@@ -322,4 +322,6 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.core import xla_env
+    xla_env.enable_compile_cache()
     main()

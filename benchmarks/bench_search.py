@@ -24,9 +24,11 @@ The search budget scales with each pair's exhaustive space size, so
 small spaces are not over-sampled and large spaces are not starved.
 
 ``--device-sweep 1,2,4,8`` additionally measures the multi-device mesh
-path (``shard_map`` fan-out + ring elite migration): each device count
-runs in a fresh subprocess whose ``XLA_FLAGS`` emulate that many host
-devices (:mod:`repro.core.xla_env`), at equal *per-device* population.
+path (``shard_map`` fan-out + ring elite migration) at equal
+*per-device* population.  On an accelerator every device count runs in
+this process over its first N devices; on the CPU each count runs in a
+fresh subprocess whose ``XLA_FLAGS`` emulate that many host devices
+(:mod:`repro.core.xla_env`).
 Every sweep point also re-runs a fixed-total-population search and
 digests its incumbents — the digests must agree across device counts and
 select-kernel backends (the determinism contract), and the scalar
@@ -279,11 +281,17 @@ def sweep_worker(devices: int, per_device_population: int, seed: int,
 
 def run_device_sweep(device_counts, per_device_population: int, seed: int,
                      n_pairs: int, steps: int, repeats: int) -> list[dict]:
-    """Fan the sweep points out over subprocesses (one per device count —
-    the emulated-device flag is fixed at backend init, so each count
-    needs its own process)."""
+    """Run every sweep point.  On an accelerator all device counts run in
+    this process over its first N devices: this process already holds the
+    chips, so a child could not open them.  On the CPU each count runs in
+    a subprocess, because the emulated-device flag is fixed at backend
+    init."""
     points = []
     for d in sorted(device_counts):
+        if not xla_env.runs_on_cpu():
+            points.append(sweep_worker(d, per_device_population, seed,
+                                       n_pairs, steps, repeats))
+            continue
         cmd = [sys.executable, "-m", "benchmarks.bench_search",
                "--sweep-worker", str(d),
                "--sweep-per-dev", str(per_device_population),
@@ -298,13 +306,14 @@ def run_device_sweep(device_counts, per_device_population: int, seed: int,
             raise RuntimeError(
                 f"device-sweep worker (devices={d}) failed:\n{proc.stderr}")
         point = json.loads(proc.stdout.strip().splitlines()[-1])
-        if "error" in point:
-            raise RuntimeError(f"device-sweep worker (devices={d}): "
-                               f"{point['error']}")
         points.append(point)
-        print(f"  devices={d}: {point['cands_per_s']:.0f} cand/s "
-              f"(pop {point['population']}) digest={point['digest']} "
-              f"gap={point['worst_gap_rel']:+.3%}")
+    for point in points:
+        if "error" in point:
+            raise RuntimeError(f"device-sweep point (devices="
+                               f"{point['devices']}): {point['error']}")
+        print(f"  devices={point['devices']}: {point['cands_per_s']:.0f} "
+              f"cand/s (pop {point['population']}) digest="
+              f"{point['digest']} gap={point['worst_gap_rel']:+.3%}")
     base = points[0]["cands_per_s"]
     for p in points:
         p["speedup_vs_1dev"] = round(p["cands_per_s"] / base, 3)
@@ -327,8 +336,7 @@ def run(pairs_limit: int | None, population: int, seed: int,
     scenarios = run_scenarios(seed)
     scaling = []
     if device_sweep:
-        print(f"Device sweep (emulated host devices, "
-              f"{sweep_per_dev} chains/device):")
+        print(f"Device sweep ({sweep_per_dev} chains/device):")
         scaling = run_device_sweep(device_sweep, sweep_per_dev, seed,
                                    sweep_pairs, sweep_steps, repeats)
 
@@ -400,8 +408,9 @@ def main(argv=None) -> dict:
     ap.add_argument("--repeats", type=int, default=2,
                     help="steady-state runs per pair; min recorded")
     ap.add_argument("--device-sweep", type=str, default=None,
-                    help="comma-separated emulated device counts, e.g. "
-                         "1,2,4,8 — each runs in a subprocess with "
+                    help="comma-separated device counts, e.g. 1,2,4,8 "
+                         "— the first N accelerator devices, or on the CPU "
+                         "a subprocess per count with "
                          "--xla_force_host_platform_device_count set")
     ap.add_argument("--sweep-per-dev", type=int, default=1024,
                     help="annealing chains per device in the sweep")
@@ -430,4 +439,6 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.core import xla_env
+    xla_env.enable_compile_cache()
     main()

@@ -120,12 +120,8 @@ def run(pairs_limit: int | None, max_transitions: int,
 
     # -- jax path: same spec through the XLA evaluator ---------------------
     jax_fields: dict = {}
-    try:
+    if not skip_jax:
         from repro.core import simulate_jax
-        have_jax = simulate_jax.HAVE_JAX and not skip_jax
-    except ImportError:
-        have_jax = False
-    if have_jax:
         t0 = time.perf_counter()
         btj = simulate_jax.simulate_spec(spec)
         t_jax_first = time.perf_counter() - t0      # compile + run
@@ -226,4 +222,6 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.core import xla_env
+    xla_env.enable_compile_cache()
     main()

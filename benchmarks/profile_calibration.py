@@ -126,4 +126,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.core import xla_env
+    xla_env.enable_compile_cache()
     raise SystemExit(main())

@@ -64,4 +64,6 @@ def main(argv: list[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.core import xla_env
+    xla_env.enable_compile_cache()
     raise SystemExit(main())

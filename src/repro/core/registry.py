@@ -216,7 +216,6 @@ ANNEAL_KNOBS = ("seed", "population", "steps", "island", "exchange_every",
 # priority 30: greedy (20) always succeeds, so "auto" never degrades this
 # far — the device search is strictly opt-in via solver="anneal".
 @register_solver("anneal", priority=30,
-                 available=lambda: _jax_available(),
                  knobs=ANNEAL_KNOBS,
                  description="device-resident island annealing over the "
                              "lowered IR (core.search_jax; jax, opt-in)")
@@ -327,21 +326,6 @@ def _scalar_simulate_assignments(platform, graphs, assignments_batch, model,
     return _scalar_simulate_batch(platform, batch, model, validate=validate)
 
 
-_JAX_OK: bool | None = None
-
-
-def _jax_available() -> bool:
-    """Probe (once) whether the jax evaluator backend can run here."""
-    global _JAX_OK
-    if _JAX_OK is None:
-        try:
-            from . import simulate_jax
-            _JAX_OK = simulate_jax.HAVE_JAX
-        except Exception:  # pragma: no cover - import storms on broken jax
-            _JAX_OK = False
-    return _JAX_OK
-
-
 def _jax_simulate_batch(*args, **kwargs):
     from . import simulate_jax
     return simulate_jax.simulate_batch(*args, **kwargs)
@@ -368,13 +352,13 @@ register_evaluator(
 # surprises in interactive use); searches opt into XLA with evaluator="jax".
 # Either way the scalar simulator stays authoritative for final incumbents.
 register_evaluator(
-    "jax", priority=50, available=_jax_available,
+    "jax", priority=50,
     simulate=simulate,                       # final incumbents stay scalar
     simulate_batch=_jax_simulate_batch,
     simulate_assignments=_jax_simulate_assignments,
     description="jax.jit+vmap lockstep evaluator over the lowered "
                 "ProblemSpec (core.simulate_jax; float64 via scoped "
-                "enable_x64)")
+                "jax.enable_x64)")
 
 
 # ---------------------------------------------------------------------------

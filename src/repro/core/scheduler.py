@@ -24,6 +24,8 @@ import inspect
 import time
 from typing import Mapping, Sequence
 
+import jax
+
 from . import registry
 from .accelerators import PLATFORMS, Platform
 from .contention import ContentionModel, ProportionalShareModel
@@ -292,6 +294,8 @@ class Scheduler:
                     self.platform, [wls for _, wls in built],
                     model or self.model, validate=False)
             except (ValueError, KeyError, RuntimeError) as exc:
+                if isinstance(exc, jax.errors.JaxRuntimeError):
+                    raise            # a broken device path, not a schedule
                 # one pathological candidate fails the whole batch call —
                 # degrade to per-row scalar evaluation so the failure stays
                 # a structured row instead of taking down the sweep.

@@ -55,21 +55,10 @@ from typing import Any, Mapping, Sequence
 
 import numpy as np
 
-try:
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-    HAVE_JAX = True
-except ImportError:  # pragma: no cover - the container ships jax
-    HAVE_JAX = False
-
-try:  # shard_map is the primary fan-out; pmap is the fallback
-    from jax.experimental.shard_map import shard_map as _shard_map
-    from jax.sharding import Mesh as _Mesh
-    from jax.sharding import PartitionSpec as _PSpec
-    HAVE_SHARD_MAP = True
-except ImportError:  # pragma: no cover - older jax
-    HAVE_SHARD_MAP = False
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh as _Mesh
+from jax.sharding import PartitionSpec as _PSpec
 
 from .accelerators import Platform
 from .contention import ContentionModel
@@ -87,13 +76,6 @@ FANOUTS = ("auto", "shard_map", "pmap")
 DEFAULT_ISLAND = 32
 #: chains per device call; population shards into island-aligned chunks.
 DEFAULT_CHUNK = 8192
-
-
-def _require_jax() -> None:
-    if not HAVE_JAX:  # pragma: no cover
-        raise RuntimeError(
-            "solver 'anneal' requires jax; install it or use "
-            "solver='bb' / 'greedy'")
 
 
 # ---------------------------------------------------------------------------
@@ -490,13 +472,13 @@ def _compiled_mesh_search(w: int, gmax: int, amax: int,
         mesh = _Mesh(np.array(devs), ("d",))
         sharded = _PSpec("d")
         repl = _PSpec()
-        fn = _shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(repl, sharded, sharded, repl, repl, repl, repl, repl),
             out_specs=(sharded, sharded),
             # while_loop bodies have no replication rule; correctness of
             # the replicated outputs is by construction (pure per-shard).
-            check_rep=False)
+            check_vma=False)
         return "flat", jax.jit(fn)
     fn = jax.pmap(body, axis_name="d", devices=devs,
                   in_axes=(None, 0, 0, None, None, None, None, None))
@@ -578,9 +560,6 @@ def _validate_knobs(population: int, island: int, exchange_every: int,
                 f"population ({population}) is not a multiple of "
                 f"island*devices ({quantum}); nearest legal value: "
                 f"population={_nearest_multiple(population, quantum)}")
-        if fanout == "shard_map" and not HAVE_SHARD_MAP:
-            raise ValueError("fanout='shard_map' is unavailable in this "
-                             "jax; nearest legal value: fanout='pmap'")
     else:
         if fanout != "auto":
             raise ValueError(
@@ -609,7 +588,7 @@ def _validate_knobs(population: int, island: int, exchange_every: int,
         "ring" if devices is not None else "island")
     fo = fanout
     if devices is not None and fo == "auto":
-        fo = "shard_map" if HAVE_SHARD_MAP else "pmap"
+        fo = "shard_map"
     return chunk, mig, fo
 
 
@@ -639,12 +618,12 @@ def anneal_search(
     at :data:`DEFAULT_CHUNK`).  ``precision="float32"`` ranks in single
     precision (the default — cheap, and the selection order is what
     matters); ``"x64"`` evaluates in float64 inside a scoped
-    ``enable_x64``.  ``backend`` selects the selection-kernel dispatch
+    ``jax.enable_x64``.  ``backend`` selects the selection-kernel dispatch
     (``pallas`` / ``pallas_interpret`` / ``xla`` / ``auto``).
 
     ``devices=N`` fans the population out over a 1-D mesh of the first N
-    visible jax devices (``fanout``: ``shard_map`` with a ``pmap``
-    fallback) with ``migrate="ring"`` cross-device elite migration; the
+    visible jax devices (``fanout``: ``shard_map`` by default, ``pmap``
+    on request) with ``migrate="ring"`` cross-device elite migration; the
     incumbent is then bit-identical for a fixed ``(seed, population,
     island, exchange_every)`` at *any* device count dividing the island
     count.  ``devices=None`` keeps the legacy sequential-chunk path
@@ -656,7 +635,6 @@ def anneal_search(
     Inconsistent knob combinations raise ``ValueError`` naming the knob
     and the nearest legal value.
     """
-    _require_jax()
     if objective not in OBJECTIVES:
         raise ValueError(f"unknown objective {objective!r}; "
                          f"one of {', '.join(OBJECTIVES)}")
@@ -775,7 +753,7 @@ def anneal_search(
                      backend=backend, devices=devices,
                      objective=objective) as search_sp:
         if precision == "x64":
-            with enable_x64():
+            with jax.enable_x64(True):
                 call()
         else:
             call()
@@ -844,7 +822,6 @@ def compile_seconds(
     same work and min-of-repeats is meaningful, unlike the legacy
     ``first_call_s - search_s`` single-sample attribution.
     """
-    _require_jax()
     _, mig, fo = _validate_knobs(population, island, 16, 1, None, devices,
                                  migrate, fanout)
 
@@ -860,12 +837,12 @@ def compile_seconds(
                              tables.kinds, objective, island, backend,
                              migrate=mig, ndev=ndev, axis_name="d")
             mesh = _Mesh(np.array(jax.devices()[:ndev]), ("d",))
-            fn = jax.jit(_shard_map(
+            fn = jax.jit(jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(_PSpec(), _PSpec("d"), _PSpec("d"), _PSpec(),
                           _PSpec(), _PSpec(), _PSpec(), _PSpec()),
                 out_specs=(_PSpec("d"), _PSpec("d")),
-                check_rep=False))
+                check_vma=False))
         else:
             # pmap has no lower()/compile() AOT path; time the
             # single-shard executable (identical body) as its proxy.
@@ -881,7 +858,7 @@ def compile_seconds(
                            population=population, devices=devices or 1,
                            backend=backend) as sp:
         if precision == "x64":
-            with enable_x64():
+            with jax.enable_x64(True):
                 dt = aot()
         else:
             dt = aot()

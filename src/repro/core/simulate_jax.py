@@ -37,7 +37,7 @@ Key differences from :mod:`repro.core.simulate_batch`:
     simulator's exceptions.
 
 By default the evaluator runs in float64 via the scoped
-``jax.experimental.enable_x64`` context (bit-compatible with the NumPy
+``jax.enable_x64(True)`` context (bit-compatible with the NumPy
 path to ~1e-9 and differentially pinned at 1e-5 by
 ``tests/test_simulate_differential.py``); ``precision="float32"`` halves
 memory traffic for accelerator-resident search where ranking, not exact
@@ -54,13 +54,8 @@ from typing import Any, Mapping, Sequence
 
 import numpy as np
 
-try:
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-    HAVE_JAX = True
-except ImportError:  # pragma: no cover - the container ships jax
-    HAVE_JAX = False
+import jax
+import jax.numpy as jnp
 
 from .accelerators import Platform
 from .contention import ContentionModel
@@ -80,13 +75,6 @@ _ERR_GUARD = 4
 #: the empirical sweet spot on the 2-core CPU reference box (see
 #: BENCH_simulate.json); accelerator deployments may prefer larger shards.
 DEFAULT_CHUNK = 16384
-
-
-def _require_jax() -> None:
-    if not HAVE_JAX:  # pragma: no cover
-        raise RuntimeError(
-            "evaluator 'jax' requires jax; install it or use "
-            "evaluator='batch' / 'scalar'")
 
 
 def _surface_params(surface) -> dict:
@@ -388,7 +376,6 @@ def simulate_spec(spec: ProblemSpec, *, precision: str = "x64",
     candidate instead of the global maximum, and shards pad to powers of
     two so arbitrary population sizes share compiled executables.
     """
-    _require_jax()
     bad = unlowerable_models(spec)
     if bad:
         raise ValueError(
@@ -429,7 +416,7 @@ def simulate_spec(spec: ProblemSpec, *, precision: str = "x64",
             err[lo:hi] = np.asarray(er)[:m]
 
     if precision == "x64":
-        with enable_x64():
+        with jax.enable_x64(True):
             call()
     else:
         call()
@@ -468,7 +455,6 @@ def simulate_batch(
     precision: str = "x64",
 ) -> BatchTimeline:
     """Lower per-candidate Workload lists and evaluate them under XLA."""
-    _require_jax()
     if len(workloads_batch) == 0:
         return _empty_batch(platform)
     return simulate_spec(lower_workloads(platform, workloads_batch, model,
@@ -486,7 +472,6 @@ def simulate_assignments(
     precision: str = "x64",
 ) -> BatchTimeline:
     """Lower fixed-graph assignment vectors and evaluate them under XLA."""
-    _require_jax()
     if len(assignments_batch) == 0:
         return _empty_batch(platform)
     return simulate_spec(lower_assignments(

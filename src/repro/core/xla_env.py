@@ -10,9 +10,15 @@ flags that must be in the environment before the first backend use:
   host) exercises the real ``shard_map``/``ppermute`` lowering of the
   multi-device search: the N shards are genuine XLA partitions, they just
   time-share the host cores.
+  It is written only for a run on the CPU backend (:func:`runs_on_cpu`):
+  on an accelerator the mesh is the first N real devices.
 * the GPU latency-hiding / async-collective flags (:data:`GPU_FLAGS`)
   let the per-device annealing loop overlap its elite-migration
   collectives with compute on real multi-GPU hosts.
+
+:func:`enable_compile_cache` turns on JAX's persistent compilation cache
+for the entry points (launchers, benchmarks, ``chip_smoke.py``), so
+processes that compile the same programs share the work.
 
 ``import jax`` alone does *not* initialize the backend — flags applied
 from ``main()`` before the first ``jax.devices()``/array op still take
@@ -26,6 +32,7 @@ same flag instead of appending a duplicate, and unrelated user-set
 from __future__ import annotations
 
 import os
+import pathlib
 import sys
 from typing import Iterable, MutableMapping
 
@@ -33,6 +40,12 @@ from ..obs import get_logger
 log = get_logger(__name__)
 
 HOST_DEVICE_FLAG = "--xla_force_host_platform_device_count"
+
+#: where the persistent compilation cache lives when
+#: ``JAX_COMPILATION_CACHE_DIR`` is unset: a fixed directory of the
+#: checkout, derived from this package's path (a cache that moves between
+#: runs is never found again).
+DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 #: GPU runtime-tuning flags (SNIPPETS.md exemplar set): overlap the
 #: mesh-search collectives with compute and keep triton fusions on.
@@ -65,6 +78,39 @@ def backend_initialized() -> bool:
         return False
 
 
+def runs_on_cpu(env: MutableMapping[str, str] = os.environ) -> bool:
+    """Does jax in this process (or one started with ``env``) compute on
+    its CPU backend?
+
+    An initialized backend answers directly.  Before that the first entry
+    of ``JAX_PLATFORMS`` decides; with it unset, jax would pick an
+    attached accelerator, which cannot be known without initializing one,
+    so the answer is True — the host-device flag only shapes the CPU
+    backend and is inert beside an accelerator.
+    """
+    if env is os.environ and backend_initialized():
+        import jax
+        return jax.default_backend() == "cpu"
+    first = env.get("JAX_PLATFORMS", "").split(",")[0].strip()
+    return first in ("", "cpu")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is
+    used as is: no other directory is set in code.  Otherwise the cache
+    goes to :data:`DEFAULT_CACHE_DIR`.  Called by the entry points only —
+    never by tests, whose compiles must not leave files behind.
+    """
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_CACHE_DIR))
+    return str(DEFAULT_CACHE_DIR)
+
+
 def merge_flags(existing: str, new: Iterable[str]) -> str:
     """Merge flag tokens into an ``XLA_FLAGS`` string; new settings win.
 
@@ -83,9 +129,10 @@ def apply(devices: int | None = None, gpu: bool = False,
           env: MutableMapping[str, str] = os.environ) -> str:
     """Install the requested XLA flags into ``env["XLA_FLAGS"]``.
 
-    ``devices=N`` emulates N host-platform devices (CPU backends);
-    ``gpu=True`` adds :data:`GPU_FLAGS`; ``extra`` appends verbatim
-    tokens.  Returns the resulting ``XLA_FLAGS`` value.  When mutating
+    ``devices=N`` emulates N host-platform devices when the run is on
+    the CPU backend (:func:`runs_on_cpu`; on an accelerator nothing is
+    written and the mesh uses N real devices); ``gpu=True`` adds
+    :data:`GPU_FLAGS`; ``extra`` appends verbatim tokens.  Returns the resulting ``XLA_FLAGS`` value.  When mutating
     this process's own ``os.environ``, warns (but still writes — a later
     subprocess inherits the env) if the backend is already initialized
     and cannot pick the flags up; copies built for subprocesses
@@ -96,7 +143,8 @@ def apply(devices: int | None = None, gpu: bool = False,
         devices = int(devices)
         if devices < 1:
             raise ValueError(f"devices must be >= 1, got {devices}")
-        flags.append(f"{HOST_DEVICE_FLAG}={devices}")
+        if runs_on_cpu(env):
+            flags.append(f"{HOST_DEVICE_FLAG}={devices}")
     if gpu:
         flags.extend(GPU_FLAGS)
     flags.extend(extra)

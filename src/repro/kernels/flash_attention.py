@@ -5,7 +5,10 @@ Online-softmax attention with explicit BlockSpec VMEM tiling, MXU-aligned
 causal / local-window / bidirectional masking with fully-masked-tile
 skipping.  Grid = (batch, q_heads, q_tiles, kv_tiles); the kv dimension is
 innermost (sequential on TPU), with the running max / denominator / output
-accumulator carried in VMEM scratch across kv tiles.
+accumulator carried in VMEM scratch across kv tiles.  The kernel sees
+head-major ``(B, H, S, D)`` operands, so each block is ``(1, 1, tile, D)``:
+the last two block dims are a sequence tile (a multiple of 8, or the whole
+sequence) and the full head dim, as Mosaic requires.
 
 Validated against :mod:`repro.kernels.ref` in interpret mode on CPU; on a
 real TPU backend the same ``pl.pallas_call`` lowers to Mosaic.
@@ -37,33 +40,34 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     # absolute positions of this tile's queries/keys (queries are the last
     # seq_q positions of the kv timeline — decode-style offset).
-    q_pos = iq * block_q + jax.lax.iota(jnp.int32, block_q) + (seq_kv - seq_q)
-    k_pos = ikv * block_kv + jax.lax.iota(jnp.int32, block_kv)
+    q_pos = (iq * block_q + (seq_kv - seq_q)
+             + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0))
+    k_pos = ikv * block_kv + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block_kv), 1)
 
     def _tile():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale     # (bq, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)             # (bkv, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)             # (bkv, dv)
+        q = q_ref[0, 0].astype(jnp.float32) * scale           # (bq, d)
+        k = k_ref[0, 0].astype(jnp.float32)                   # (bkv, d)
+        v = v_ref[0, 0].astype(jnp.float32)                   # (bkv, dv)
         # zero the padded kv tail: p is 0 there but 0*NaN would poison acc
-        kv_valid = (k_pos < seq_kv)[:, None]
+        kv_valid = (ikv * block_kv + jax.lax.broadcasted_iota(
+            jnp.int32, (block_kv, 1), 0)) < seq_kv
         k = jnp.where(kv_valid, k, 0.0)
         v = jnp.where(kv_valid, v, 0.0)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        mask = jnp.ones((block_q, block_kv), dtype=jnp.bool_)
+        mask = (k_pos < seq_kv) & (q_pos < seq_kv)
         if causal:
-            mask &= k_pos[None, :] <= q_pos[:, None]
+            mask &= k_pos <= q_pos
         if window is not None:
-            mask &= k_pos[None, :] > q_pos[:, None] - window
-        mask &= (k_pos[None, :] < seq_kv) & (q_pos[:, None] <
-                                             seq_kv)
+            mask &= k_pos > q_pos - window
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]                                   # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + p.sum(axis=1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
@@ -86,7 +90,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ikv == n_kv - 1)
     def _finalize():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -110,24 +114,25 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
     kernel = functools.partial(
         _kernel, scale=scale, causal=causal, window=window,
         block_q=block_q, block_kv=block_kv, seq_q=sq, seq_kv=skv)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, d),
-                         lambda b_, h, iq, ikv: (b_, iq, h, 0)),
-            pl.BlockSpec((1, block_kv, 1, d),
-                         lambda b_, h, iq, ikv, g=group: (b_, ikv, h // g, 0)),
-            pl.BlockSpec((1, block_kv, 1, dv),
-                         lambda b_, h, iq, ikv, g=group: (b_, ikv, h // g, 0)),
+            pl.BlockSpec((1, 1, block_q, d),
+                         lambda b_, h, iq, ikv: (b_, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_kv, d),
+                         lambda b_, h, iq, ikv, g=group: (b_, h // g, ikv, 0)),
+            pl.BlockSpec((1, 1, block_kv, dv),
+                         lambda b_, h, iq, ikv, g=group: (b_, h // g, ikv, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, dv),
-                               lambda b_, h, iq, ikv: (b_, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq, hq, dv), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, dv),
+                               lambda b_, h, iq, ikv: (b_, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, hq, sq, dv), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),          # running max
-            pltpu.VMEM((block_q,), jnp.float32),          # denominator
+            pltpu.VMEM((block_q, 1), jnp.float32),        # running max
+            pltpu.VMEM((block_q, 1), jnp.float32),        # denominator
             pltpu.VMEM((block_q, dv), jnp.float32),       # output accum
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)))
+    return out.transpose(0, 2, 1, 3)

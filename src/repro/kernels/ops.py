@@ -7,8 +7,10 @@
                            recurrences).  Used on CPU and for the dry-run so
                            the lowered HLO is backend-portable.
 
-``backend="auto"`` picks pallas on TPU, xla elsewhere.  All backends are
-bit-compatible up to float tolerance with :mod:`repro.kernels.ref`.
+``backend="auto"`` picks pallas on TPU, xla elsewhere (:func:`resolve`,
+which also counts every choice in the ``kernel_dispatch`` metric so a run
+can print which path each kernel took).  All backends are bit-compatible
+up to float tolerance with :mod:`repro.kernels.ref`.
 """
 from __future__ import annotations
 
@@ -18,6 +20,8 @@ from typing import Literal
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.obs import get_registry
 
 from . import decode_attention as _dec
 from . import flash_attention as _fa
@@ -32,8 +36,39 @@ Backend = Literal["auto", "xla", "pallas", "pallas_interpret", "ref", "stub"]
 # HBM).  The dry-run adds the kernels' flops analytically.
 
 
-def _auto() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+def resolve(kernel: str, backend: str, *, pallas_ok: bool = True) -> str:
+    """The backend one call of ``kernel`` takes, counted in the
+    ``kernel_dispatch`` metric under ``kernel``/``backend`` labels.
+
+    ``auto`` is pallas on TPU when the call site allows it (``pallas_ok``:
+    big enough to pay for a launch, a dtype Mosaic supports), xla
+    otherwise; explicit backends pass through.  Dispatch happens while
+    tracing, so under ``jax.jit`` the count is per trace, not per run.
+    """
+    b = backend
+    if b == "auto":
+        b = "pallas" if (jax.default_backend() == "tpu" and pallas_ok) \
+            else "xla"
+    get_registry().counter(
+        "kernel_dispatch", "kernel calls dispatched (traced) per backend"
+    ).labels(kernel=kernel, backend=b).inc()
+    return b
+
+
+LANES = 128
+
+
+def lane_tiling(n: int, block: int) -> tuple[int, int]:
+    """Row blocking of a flat length-``n`` array laid out as lane-dense
+    ``(rows, LANES)`` tiles: returns ``(row_block, n_blocks)`` with the
+    row block a multiple of 8 near ``block`` elements, or all the rows
+    when they fit in one block — the two shapes Mosaic accepts.  Callers
+    pad the array to ``row_block * n_blocks * LANES`` elements."""
+    rows = -(-n // LANES)
+    rb = max(8, -(-(block // LANES) // 8) * 8)
+    if rows <= rb:
+        return rows, 1
+    return rb, -(-rows // rb)
 
 
 # ---------------------------------------------------------------------------
@@ -43,7 +78,7 @@ def attention(q, k, v, *, causal: bool = True, window: int | None = None,
               block_q: int = 1024, block_kv: int = 1024,
               backend: Backend = "auto"):
     """Multi-head GQA attention. q: (B,Sq,Hq,D); k,v: (B,Skv,Hkv,D)."""
-    b = _auto() if backend == "auto" else backend
+    b = resolve("attention", backend)
     if b == "stub":
         hq, hkv = q.shape[2], k.shape[2]
         kv = (k.sum(1) + v.sum(1))[:, None]            # reads k, v fully
@@ -110,7 +145,7 @@ def _attention_xla(q, k, v, *, causal, window, block_kv):
 # ---------------------------------------------------------------------------
 def decode_attention(q, k_cache, v_cache, lengths, *,
                      backend: Backend = "auto"):
-    b = _auto() if backend == "auto" else backend
+    b = resolve("decode_attention", backend)
     if b == "stub":
         hq, hkv = q.shape[2], k_cache.shape[2]
         kv = (k_cache.sum(1) + v_cache.sum(1))[:, None]
@@ -144,7 +179,7 @@ def _decode_xla(q, k_cache, v_cache, lengths):
 # ---------------------------------------------------------------------------
 def linear_scan(a, b, h0=None, *, backend: Backend = "auto"):
     """h_t = a_t h_{t-1} + b_t over axis 1.  a, b: (B, S, D)."""
-    be = _auto() if backend == "auto" else backend
+    be = resolve("linear_scan", backend)
     if be == "stub":
         h = (a * b).astype(a.dtype)                    # reads a, b; writes h
         last = h[:, -1].astype(jnp.float32) + (
@@ -182,7 +217,7 @@ def _linear_scan_xla(a, b, h0=None):
 # RWKV-6 recurrence
 # ---------------------------------------------------------------------------
 def rwkv6(r, k, v, w, u, state0=None, *, backend: Backend = "auto"):
-    be = _auto() if backend == "auto" else backend
+    be = resolve("rwkv6", backend)
     if be == "stub":
         g = (r + k + w).sum(-1, keepdims=True)         # reads r, k, w
         y = (v * g).astype(v.dtype)                    # reads v, writes y

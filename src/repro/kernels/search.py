@@ -7,7 +7,8 @@ population must be *selected* — Metropolis-accept each proposal against the
 chain's current state and fold strict improvements into the per-chain
 incumbent.  That step is one elementwise decision broadcast across a
 (P, L) block of assignment rows: a natural Pallas kernel, blocked over the
-chain axis with the row length riding whole.
+chain axis with the row length riding whole and each chain's scalars as a
+``(P, 1)`` column blocked alongside its row.
 
 Backends follow the repo-wide dispatch idiom (:mod:`repro.kernels.slowdown`):
 
@@ -17,7 +18,8 @@ Backends follow the repo-wide dispatch idiom (:mod:`repro.kernels.slowdown`):
                            (:func:`repro.kernels.ref.anneal_select`), used
                            on CPU where a kernel launch cannot pay for
                            itself;
-  * ``auto``             — pallas on TPU for big populations, xla otherwise.
+  * ``auto``             — pallas on TPU for big float32 populations, xla
+                           otherwise.
 
 All backends compute the same accept predicate from the same uniform draws,
 so the search incumbent is bit-identical across them — pinned by
@@ -31,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .ops import resolve
 from .ref import anneal_select as _ref_select
 
 #: below this many chains a pallas launch cannot pay for itself —
@@ -38,60 +41,55 @@ from .ref import anneal_select as _ref_select
 _MIN_PALLAS_CHAINS = 1024
 
 
-def _kernel(cur_ref, prop_ref, best_ref, curo_ref, propo_ref, besto_ref,
-            u_ref, temp_ref, out_cur_ref, out_curo_ref, out_best_ref,
+def _kernel(temp_ref, cur_ref, prop_ref, best_ref, curo_ref, propo_ref,
+            besto_ref, u_ref, out_cur_ref, out_curo_ref, out_best_ref,
             out_besto_ref):
-    cur = cur_ref[...]                       # (B, L) int32
-    prop = prop_ref[...]
-    best = best_ref[...]
-    curo = curo_ref[...][0]                  # (B,)
-    propo = propo_ref[...][0]
-    besto = besto_ref[...][0]
-    u = u_ref[...][0]
-    temp = jnp.maximum(temp_ref[0, 0], jnp.asarray(1e-30, curo.dtype))
+    curo = curo_ref[...]                     # (B, 1): one chain per row
+    propo = propo_ref[...]
+    besto = besto_ref[...]
+    temp = jnp.maximum(temp_ref[...], 1e-30)         # (1, 1)
     delta = propo - curo
-    accept = (delta <= 0) | (u < jnp.exp(-delta / temp))
+    accept = (delta <= 0) | (u_ref[...] < jnp.exp(-delta / temp))
     accept &= jnp.isfinite(propo)
     improved = propo < besto
-    out_cur_ref[...] = jnp.where(accept[:, None], prop, cur)
-    out_curo_ref[...] = jnp.where(accept, propo, curo)[None, :]
-    out_best_ref[...] = jnp.where(improved[:, None], prop, best)
-    out_besto_ref[...] = jnp.where(improved, propo, besto)[None, :]
+    out_cur_ref[...] = jnp.where(accept, prop_ref[...], cur_ref[...])
+    out_curo_ref[...] = jnp.where(accept, propo, curo)
+    out_best_ref[...] = jnp.where(improved, prop_ref[...], best_ref[...])
+    out_besto_ref[...] = jnp.where(improved, propo, besto)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def _pallas_select(cur, prop, best, cur_obj, prop_obj, best_obj, u, temp, *,
                    block: int, interpret: bool):
     p, l = cur.shape
+    if p <= block:
+        block = p                       # one block: the full array
     nb = pl.cdiv(p, block)
     pad = nb * block - p
-    if pad:
-        cur, prop, best = (jnp.pad(a, ((0, pad), (0, 0)))
-                           for a in (cur, prop, best))
-        cur_obj, prop_obj, best_obj, u = (
-            jnp.pad(a, (0, pad)) for a in (cur_obj, prop_obj, best_obj, u))
+    # chain-major layout: the (P, L) rows and the (P, 1) per-chain scalars
+    # share the row blocking, so every block's last dim is the full array
+    # dim and its row count a multiple of 8 (or the whole population).
+    rows = [jnp.pad(a, ((0, pad), (0, 0))) for a in (cur, prop, best)]
+    cols = [jnp.pad(a, (0, pad)).reshape(-1, 1)
+            for a in (cur_obj, prop_obj, best_obj, u)]
     row = pl.BlockSpec((block, l), lambda i: (i, 0))
-    col = pl.BlockSpec((1, block), lambda i: (i, 0))
+    col = pl.BlockSpec((block, 1), lambda i: (i, 0))
     dt = cur_obj.dtype
     out = pl.pallas_call(
         _kernel,
         grid=(nb,),
-        in_specs=[row, row, row, col, col, col, col,
-                  pl.BlockSpec((1, 1), lambda i: (0, 0))],
+        in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)),
+                  row, row, row, col, col, col, col],
         out_specs=[row, col, row, col],
         out_shape=[
             jax.ShapeDtypeStruct((nb * block, l), cur.dtype),
-            jax.ShapeDtypeStruct((nb, block), dt),
+            jax.ShapeDtypeStruct((nb * block, 1), dt),
             jax.ShapeDtypeStruct((nb * block, l), cur.dtype),
-            jax.ShapeDtypeStruct((nb, block), dt),
+            jax.ShapeDtypeStruct((nb * block, 1), dt),
         ],
         interpret=interpret,
-    )(cur, prop, best,
-      cur_obj.reshape(nb, block), prop_obj.reshape(nb, block),
-      best_obj.reshape(nb, block), u.reshape(nb, block),
-      temp.reshape(1, 1).astype(dt))
-    return (out[0][:p], out[1].reshape(-1)[:p],
-            out[2][:p], out[3].reshape(-1)[:p])
+    )(temp.reshape(1, 1).astype(dt), *rows, *cols)
+    return (out[0][:p], out[1][:p, 0], out[2][:p], out[3][:p, 0])
 
 
 def anneal_select(cur, prop, best, cur_obj, prop_obj, best_obj, u, temp, *,
@@ -116,16 +114,15 @@ def anneal_select(cur, prop, best, cur_obj, prop_obj, best_obj, u, temp, *,
     best_obj = jnp.asarray(best_obj, dt)
     u = jnp.asarray(u, dt)
     temp = jnp.asarray(temp, dt)
-    b = backend
-    if b == "auto":
-        big = (global_lanes or cur.shape[0]) >= _MIN_PALLAS_CHAINS
-        b = "pallas" if (jax.default_backend() == "tpu" and big) else "xla"
+    # Mosaic has no float64: x64-precision searches select on XLA.
+    b = resolve("anneal_select", backend, pallas_ok=(
+        (global_lanes or cur.shape[0]) >= _MIN_PALLAS_CHAINS
+        and dt == jnp.float32))
     if b in ("xla", "ref"):
         return _ref_select(cur, jnp.asarray(prop), jnp.asarray(best),
                            cur_obj, prop_obj, best_obj, u, temp)
     if b in ("pallas", "pallas_interpret"):
         return _pallas_select(
             cur, jnp.asarray(prop), jnp.asarray(best), cur_obj, prop_obj,
-            best_obj, u, temp, block=min(block, max(8, cur.shape[0])),
-            interpret=(b == "pallas_interpret"))
+            best_obj, u, temp, block=block, interpret=(b == "pallas_interpret"))
     raise ValueError(f"unknown backend {b!r}")

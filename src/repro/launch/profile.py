@@ -245,9 +245,10 @@ def main(argv=None) -> int:
     ap.add_argument("--solve-tolerance", type=float, default=0.05,
                     help="max generating-vs-measured objective deviation")
     ap.add_argument("--devices", type=int, default=None, metavar="N",
-                    help="fan --solver anneal solves over N devices "
-                         "(emulated on CPU hosts via "
-                         "--xla_force_host_platform_device_count, applied "
+                    help="fan --solver anneal solves over N devices: "
+                         "the first N accelerator devices, or on the CPU N "
+                         "emulated host devices "
+                         "(--xla_force_host_platform_device_count, applied "
                          "before jax initializes)")
     ap.add_argument("--search-budget-ms", type=float, default=None,
                     metavar="MS",
@@ -318,4 +319,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.core import xla_env
+    xla_env.enable_compile_cache()
     raise SystemExit(main())

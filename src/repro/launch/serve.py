@@ -1,8 +1,9 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Single-model continuous-batching service on reduced configs (CPU);
---co-arch plans HaX-CoNN concurrent co-serving for full configs on the
-production pod split; --gateway additionally *serves* both models
+Single-model continuous-batching service over the registered config at
+its published width (``--reduced`` serves the tiny smoke-test sibling
+instead, for CPU runs, tests and CI); --co-arch plans HaX-CoNN concurrent
+co-serving for full configs on the production pod split; --gateway additionally *serves* both models
 concurrently through the contention-aware multi-tenant gateway (phase-aware
 schedule, shared KV budget, dynamic re-scheduling).
 
@@ -83,7 +84,7 @@ def _run_gateway(args) -> int:
     from repro.serve.gateway import (GatewayConfig, MultiTenantGateway,
                                      TenantSpec)
     archs = [args.arch, args.co_arch]
-    specs = [TenantSpec(a, configs.get(a).reduced(),
+    specs = [TenantSpec(a, _config(a, args.reduced),
                         plan_cfg=configs.get(a), max_slots=4, capacity=96,
                         max_new=args.max_new)
              for a in archs]
@@ -229,6 +230,10 @@ def main(argv=None):
                          "the multi-tenant gateway (requires --co-arch)")
     ap.add_argument("--budget-slots", type=int, default=0,
                     help="shared KV budget in slot units (0 = unlimited)")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve each model's tiny smoke-test sibling "
+                         "(ModelConfig.reduced) instead of its published "
+                         "width: the size for CPU runs, tests and CI")
     ap.add_argument("--shape", default="decode_32k")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=16)
@@ -295,10 +300,11 @@ def main(argv=None):
                          "fail listing the registered solvers.")
     ap.add_argument("--devices", type=int, default=None, metavar="N",
                     help="fan the anneal search over N devices "
-                         "(shard_map mesh with ring elite migration). "
-                         "Applied as --xla_force_host_platform_device_count "
-                         "before jax initializes, so CPU-only hosts emulate "
-                         "an N-device mesh; requires --solver anneal")
+                         "(shard_map mesh with ring elite migration): the "
+                         "first N accelerator devices, or on the CPU N "
+                         "emulated host devices "
+                         "(--xla_force_host_platform_device_count, applied "
+                         "before jax initializes); requires --solver anneal")
     ap.add_argument("--search-budget-ms", type=float, default=None,
                     metavar="MS",
                     help="wall-clock budget for each fresh anneal solve: "
@@ -411,17 +417,39 @@ def _run_concurrent(args) -> int:
     return 0
 
 
+def _config(arch: str, reduced: bool):
+    cfg = configs.get(arch)
+    return cfg.reduced() if reduced else cfg
+
+
+def build_engine(arch: str, *, reduced: bool = False, backend: str = "auto",
+                 params=None) -> ServingEngine:
+    """The single-model service on 4 slots of 128 tokens: ``arch`` at its
+    published width (or its reduced sibling) over seeded random
+    parameters, unless ``params`` are given — e.g. one set shared by
+    engines on different kernel backends."""
+    model = build(_config(arch, reduced), backend=backend)
+    if params is None:
+        params = model.init(jax.random.PRNGKey(0))
+    return ServingEngine(model, params, max_slots=4, capacity=128)
+
+
+def submit_requests(eng: ServingEngine, n: int, *, max_new: int):
+    """Submit ``n`` seeded random prompts of 8 to 64 tokens (uniform, as
+    the fleet traffic generator draws them); returns the requests."""
+    rng = np.random.default_rng(0)
+    return [eng.submit(rng.integers(0, eng.model.cfg.vocab,
+                                    size=int(rng.integers(8, 65))),
+                       max_new=max_new)
+            for _ in range(n)]
+
+
 def _run_single(args) -> int:
-    cfg = configs.get(args.arch).reduced()
-    if not cfg.has_decode:
+    if not configs.get(args.arch).has_decode:
         print(f"{args.arch} is encoder-only: no decode service")
         return 1
-    model = build(cfg, backend="auto")
-    params = model.init(jax.random.PRNGKey(0))
-    eng = ServingEngine(model, params, max_slots=4, capacity=128)
-    rng = np.random.default_rng(0)
-    for _ in range(args.requests):
-        eng.submit(rng.integers(0, cfg.vocab, size=8), max_new=args.max_new)
+    eng = build_engine(args.arch, reduced=args.reduced)
+    submit_requests(eng, args.requests, max_new=args.max_new)
     done = eng.run_until_drained()
     print(f"served {len(done)} requests, "
           f"{sum(len(r.tokens) for r in done)} tokens, "
@@ -430,4 +458,6 @@ def _run_single(args) -> int:
 
 
 if __name__ == "__main__":
+    from repro.core import xla_env
+    xla_env.enable_compile_cache()
     raise SystemExit(main())

@@ -9,7 +9,8 @@ module is that antagonist:
   operands, writes 1: a saxpy), dispatched across the repo-wide backend
   idiom (:mod:`repro.kernels.ops`): a Pallas kernel on TPU
   (``pallas``/``pallas_interpret``) or the identical jnp expression under
-  jit elsewhere (``xla``); ``auto`` picks by ``jax.default_backend()``.
+  jit elsewhere (``xla``); ``auto`` picks by ``jax.default_backend()``
+  (:func:`repro.kernels.ops.resolve`).
 * :func:`measure_peak_bandwidth` — calibrate the probe itself: achieved
   bytes/s of back-to-back full-duty streaming, which anchors duty-cycled
   demand levels to fractions of *measured* capacity.
@@ -30,6 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ops import LANES, lane_tiling, resolve
+
 from .harness import TimerConfig, measure_wallclock
 
 #: streaming traffic per pass: x (read) + y (read) + out (write).
@@ -43,21 +46,19 @@ def _stream_kernel(x_ref, y_ref, o_ref):
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def _pallas_stream(x, y, *, block: int, interpret: bool):
     n = x.shape[0]
-    nb = pl.cdiv(n, block)
-    pad = nb * block - n
-    if pad:
-        x = jnp.pad(x, (0, pad))
-        y = jnp.pad(y, (0, pad))
+    rb, nb = lane_tiling(n, block)
+    pad = nb * rb * LANES - n
+    tile = pl.BlockSpec((rb, LANES), lambda i: (i, 0))
     out = pl.pallas_call(
         _stream_kernel,
         grid=(nb,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0)),
-                  pl.BlockSpec((1, block), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, block), x.dtype),
+        in_specs=[tile, tile],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((nb * rb, LANES), x.dtype),
         interpret=interpret,
-    )(x.reshape(nb, block), y.reshape(nb, block))
-    return out.reshape(nb * block)[:n]
+    )(jnp.pad(x, (0, pad)).reshape(-1, LANES),
+      jnp.pad(y, (0, pad)).reshape(-1, LANES))
+    return out.reshape(-1)[:n]
 
 
 @jax.jit
@@ -65,17 +66,13 @@ def _xla_stream(x, y):
     return x * jnp.float32(1.0000001) + y
 
 
-def _auto() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
-
-
-def stream_once(x, y, *, backend: str = "auto", block: int = 4096):
+def stream_once(x, y, *, backend: str = "auto", block: int = 65536):
     """One antagonist pass: reads ``x``/``y`` fully, writes their saxpy."""
-    b = _auto() if backend == "auto" else backend
+    b = resolve("stream", backend)
     if b == "xla":
         return _xla_stream(x, y)
     if b in ("pallas", "pallas_interpret"):
-        return _pallas_stream(x, y, block=min(block, x.shape[0]),
+        return _pallas_stream(x, y, block=block,
                               interpret=(b == "pallas_interpret"))
     raise ValueError(f"unknown backend {b!r}")
 

@@ -72,7 +72,8 @@ class EngineMetrics:
 class ServingEngine:
     def __init__(self, model: Model, params, max_slots: int = 4,
                  capacity: int = 256,
-                 admission_gate: Callable[[Request], bool] | None = None):
+                 admission_gate: Callable[[Request], bool] | None = None,
+                 on_logits: Callable[[str, jax.Array], None] | None = None):
         self.model = model
         self.params = params
         self.max_slots = max_slots
@@ -91,6 +92,11 @@ class ServingEngine:
         #: consulted before each queue->slot admission; ``False`` defers the
         #: head request (FIFO is preserved: admission stops for this step).
         self.admission_gate = admission_gate
+        #: observer of the device logits the engine samples from, called
+        #: with ``("prefill", (1, 1, vocab))`` per admission and
+        #: ``("decode", (max_slots, 1, vocab))`` per step, in that order;
+        #: it gets the arrays as produced, with no host sync added.
+        self.on_logits = on_logits
         self.counters = EngineMetrics()
 
     # ------------------------------------------------------------------
@@ -141,6 +147,8 @@ class ServingEngine:
             req = self.queue.popleft()
             batch = {"token_ids": jnp.asarray(req.prompt)[None]}
             logits, cache1 = self._prefill(self.params, batch)
+            if self.on_logits is not None:
+                self.on_logits("prefill", logits)
             # splice the single-request cache into the batched cache.
             # group caches are stacked (n_groups, batch, ...); tail caches
             # are (batch, ...).
@@ -176,6 +184,8 @@ class ServingEngine:
         batch = {"token_ids": jnp.asarray(self.last_tok)[:, None],
                  "lengths": jnp.asarray(self.lengths)}
         logits, self.caches = self._decode(self.params, self.caches, batch)
+        if self.on_logits is not None:
+            self.on_logits("decode", logits)
         toks = np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
         self.counters.last_step_ms = (time.perf_counter() - t0) * 1e3
         self.counters.decode_ms_total += self.counters.last_step_ms

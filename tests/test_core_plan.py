@@ -121,8 +121,6 @@ class TestSolverKnobs:
                                       max_transitions=1,
                                       population=[512])
 
-    @pytest.mark.skipif(not registry.get_solver("anneal").available(),
-                        reason="jax not installed")
     def test_knobs_reach_the_solver_and_its_provenance(self):
         sched = small_scheduler()
         plan = sched.solve(DNNS, solver="anneal", max_transitions=1,
@@ -207,8 +205,6 @@ class TestPlanRoundTrip:
 # ---------------------------------------------------------------------------
 
 class TestSolverProvenance:
-    @pytest.mark.skipif(not registry.get_solver("anneal").available(),
-                        reason="jax not installed")
     def test_anneal_plan_records_params_and_round_trips(self):
         sched = small_scheduler()
         plan = sched.solve(DNNS, solver="anneal", max_transitions=1,

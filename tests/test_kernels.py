@@ -81,9 +81,25 @@ class TestDecodeAttention:
             got.astype(jnp.float32), want.astype(jnp.float32), **tol(dtype))
 
 
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_kv_tiles_past_length_are_skipped(self, dtype):
+        """Several kv tiles per sequence: tiles past a sequence's length
+        are clamped to its last live tile and never computed."""
+        from repro.kernels.decode_attention import decode_attention
+        B, S, Hq, Hkv, D = 4, 128, 8, 2, 64
+        q = rand(KEYS[0], (B, 1, Hq, D), dtype)
+        k = rand(KEYS[1], (B, S, Hkv, D), dtype)
+        v = rand(KEYS[2], (B, S, Hkv, D), dtype)
+        lengths = jnp.array([1, 31, 32, 128], jnp.int32)
+        got = decode_attention(q, k, v, lengths, block_kv=32, interpret=True)
+        want = ref.attention(q, k, v, causal=True, lengths=lengths)
+        np.testing.assert_allclose(
+            got.astype(jnp.float32), want.astype(jnp.float32), **tol(dtype))
+
+
 class TestLinearScan:
     @pytest.mark.parametrize("shape", [(2, 64, 32), (1, 100, 256),
-                                       (3, 33, 128)])
+                                       (3, 33, 128), (1, 300, 128)])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
     @pytest.mark.parametrize("with_h0", [False, True])
@@ -117,7 +133,7 @@ class TestLinearScan:
 
 class TestRWKV6:
     @pytest.mark.parametrize("shape", [(1, 32, 2, 16, 16), (2, 17, 4, 32, 32),
-                                       (1, 64, 1, 64, 64)])
+                                       (1, 64, 1, 64, 64), (1, 150, 2, 16, 16)])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
     def test_vs_oracle(self, shape, dtype, backend):
@@ -205,12 +221,14 @@ class TestSlowdownSurfaceKernel:
         from repro.kernels.slowdown import piecewise_slowdown
         m = self._model()
         rng = np.random.default_rng(1)
-        own = rng.uniform(0.05, 1.3, size=777).astype(np.float32)
-        ext = rng.uniform(0.05, 1.3, size=777).astype(np.float32)
+        # 2777 points in blocks of 8 rows x 128 lanes: three blocks, the
+        # last one padded
+        own = rng.uniform(0.05, 1.3, size=2777).astype(np.float32)
+        ext = rng.uniform(0.05, 1.3, size=2777).astype(np.float32)
         a = np.asarray(piecewise_slowdown(own, ext, m.own_knots,
                                           m.ext_knots, m.table,
                                           backend="pallas_interpret",
-                                          block=256))
+                                          block=1024))
         b = np.asarray(piecewise_slowdown(own, ext, m.own_knots,
                                           m.ext_knots, m.table,
                                           backend="xla"))

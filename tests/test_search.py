@@ -36,13 +36,7 @@ from repro.core.graph import DNNGraph, LayerGroup
 from repro.core.simulate import Workload, simulate
 from repro.core.solver_bb import enumerate_assignments
 
-try:
-    from repro.core import search_jax
-    HAVE_JAX = search_jax.HAVE_JAX
-except ImportError:  # pragma: no cover
-    HAVE_JAX = False
-
-pytestmark = pytest.mark.skipif(not HAVE_JAX, reason="jax not installed")
+from repro.core import search_jax
 
 FIXTURES = sorted(
     (pathlib.Path(__file__).parent / "fixtures" / "plans").glob("*.json"))
@@ -128,10 +122,11 @@ class TestSelectKernelParity:
         temp = np.asarray(0.37, dtype)
         return cur, prop, best, curo, propo, besto, u, temp
 
+    @pytest.mark.parametrize("p", [64, 600])   # one block; padded blocks
     @pytest.mark.parametrize("dtype", [np.float32, np.float64])
-    def test_xla_matches_pallas_interpret_bitwise(self, dtype):
+    def test_xla_matches_pallas_interpret_bitwise(self, dtype, p):
         from repro.kernels.search import anneal_select
-        args = self._inputs(dtype=dtype)
+        args = self._inputs(p=p, dtype=dtype)
         ref = anneal_select(*args, backend="xla")
         ker = anneal_select(*args, backend="pallas_interpret")
         for r, k in zip(ref, ker):

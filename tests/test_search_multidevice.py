@@ -31,14 +31,11 @@ import textwrap
 import pytest
 
 from _prop import examples, given, search_problems, settings
+# imported here, not inside a property test: importing a module of
+# @given tests from within one trips hypothesis' nested-given check
+from test_search import scalar_objective
 
-try:
-    from repro.core import search_jax
-    HAVE_JAX = search_jax.HAVE_JAX
-except ImportError:  # pragma: no cover
-    HAVE_JAX = False
-
-pytestmark = pytest.mark.skipif(not HAVE_JAX, reason="jax not installed")
+from repro.core import search_jax
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 
@@ -125,8 +122,6 @@ class TestCrossDeviceDeterminism:
     def test_pmap_matches_shard_map(self, tables, ref):
         if _device_count() < 2:
             pytest.skip("needs >= 2 jax devices")
-        if not search_jax.HAVE_SHARD_MAP:
-            pytest.skip("shard_map unavailable in this jax")
         sm = search_jax.anneal_search(tables, devices=2,
                                       fanout="shard_map", **KW)
         pm = search_jax.anneal_search(tables, devices=2, fanout="pmap",
@@ -177,7 +172,6 @@ class TestDifferentialUnderSharding:
     @given(prob=search_problems())
     @settings(max_examples=examples(4))
     def test_device_objective_matches_scalar_rerun(self, prob):
-        from test_search import scalar_objective
         platform, graphs, model, its, deps, arr = prob
         mt = max(len(g) for g in graphs)
         tbl = search_jax.build_tables(

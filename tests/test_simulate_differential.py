@@ -42,13 +42,7 @@ TOL = 1e-6
 #: the jax evaluator's cross-backend contract (float32-safe inputs).
 JAX_TOL = 1e-5
 
-try:
-    from repro.core import simulate_jax
-    HAVE_JAX = simulate_jax.HAVE_JAX
-except ImportError:  # pragma: no cover
-    HAVE_JAX = False
-
-needs_jax = pytest.mark.skipif(not HAVE_JAX, reason="jax not installed")
+from repro.core import simulate_jax
 
 
 def assert_equivalent(ref, res, context="", tol=TOL):
@@ -261,7 +255,6 @@ class TestTargetedDifferential:
         assert bt.objective("latency").shape == (0,)
 
 
-@needs_jax
 class TestJaxDifferential:
     """Three-way parity: the XLA evaluator against scalar and batch.
 
@@ -385,7 +378,6 @@ class TestJaxDifferential:
             .result(0), tol=JAX_TOL)
 
 
-@needs_jax
 class TestJaxGoldenPlans:
     """The jax evaluator must reproduce the pinned Table-6 fixtures."""
 
@@ -440,7 +432,6 @@ class TestDifferentialSweep:
         res = simulate_batch(platform, [wls], model).result(0)
         assert_equivalent(ref, res, f"seed={seed}")
 
-    @needs_jax
     @given(seed=st.integers(min_value=20_000_001, max_value=30_000_000))
     @settings(max_examples=examples(150), deadline=None)
     def test_jax_matches_scalar_wide(self, seed):

@@ -1,0 +1,116 @@
+"""Ahead-of-time compiles of every Pallas kernel for a TPU v5e.
+
+Nothing runs: each kernel is lowered and compiled by the TPU compiler for
+a described (not attached) v5e chip at the widths the main path uses, so
+Mosaic's tiling rules, scalar-prefetch use and VMEM budget are checked on
+every change without a chip.  Each compiled program must contain the
+kernel (``tpu_custom_call``); a kernel that fell back to XLA would not.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and under pytest-xdist
+every worker imports this file.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels import decode_attention, flash_attention, rglru, rwkv6
+from repro.kernels import search, slowdown
+from repro.profiling import probes
+
+#: stablelm-1.6b attention widths: 32 heads (MHA) of 64 dims
+HEADS, HEAD_DIM = 32, 64
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip cannot be read back here: keep
+        # the persistent cache out of it
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _sds(shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _kv(seq, dtype=jnp.bfloat16):
+    return _sds((1, seq, HEADS, HEAD_DIM), dtype)
+
+
+#: name -> (function, argument shapes); every function calls the Pallas
+#: kernel itself, with interpret=False.
+CASES = {
+    "anneal_select_p8192": (
+        lambda c, p, b, co, po, bo, u, t: search._pallas_select(
+            c, p, b, co, po, bo, u, t, block=256, interpret=False),
+        (_sds((8192, 32), jnp.int32),) * 3 + (_sds((8192,)),) * 4
+        + (_sds(()),)),
+    "piecewise_slowdown_n16384": (
+        lambda o, e, ok, ek, t: slowdown._pallas_piecewise(
+            o, e, ok, ek, t, block=8192, interpret=False),
+        (_sds((16384,)), _sds((16384,)), _sds((6,)), _sds((8,)),
+         _sds((6, 8)))),
+    "flash_attention_prompt8": (
+        lambda q, k, v: flash_attention.flash_attention(q, k, v),
+        (_kv(8),) * 3),
+    "flash_attention_prompt512": (
+        lambda q, k, v: flash_attention.flash_attention(q, k, v),
+        (_kv(512),) * 3),
+    "decode_attention_4slots_cap128": (
+        lambda q, k, v, n: decode_attention.decode_attention(q, k, v, n),
+        (_sds((4, 1, HEADS, HEAD_DIM), jnp.bfloat16),
+         _sds((4, 128, HEADS, HEAD_DIM), jnp.bfloat16),
+         _sds((4, 128, HEADS, HEAD_DIM), jnp.bfloat16),
+         _sds((4,), jnp.int32))),
+    "decode_attention_gqa_cap2048": (
+        lambda q, k, v, n: decode_attention.decode_attention(q, k, v, n),
+        (_sds((4, 1, 24, 128), jnp.bfloat16),
+         _sds((4, 2048, 8, 128), jnp.bfloat16),
+         _sds((4, 2048, 8, 128), jnp.bfloat16),
+         _sds((4,), jnp.int32))),
+    "rglru_scan_prefill": (
+        lambda a, b: rglru.rglru_scan(a, b),
+        (_sds((2, 300, 2560), jnp.bfloat16),) * 2),
+    "rglru_scan_decode": (
+        lambda a, b: rglru.rglru_scan(a, b),
+        (_sds((4, 1, 2560), jnp.bfloat16),) * 2),
+    "rwkv6_scan_prefill": (
+        lambda r, k, v, w, u: rwkv6.rwkv6_scan(r, k, v, w, u),
+        (_sds((1, 200, 64, 64), jnp.bfloat16),) * 4
+        + (_sds((64, 64), jnp.bfloat16),)),
+    "rwkv6_scan_decode": (
+        lambda r, k, v, w, u: rwkv6.rwkv6_scan(r, k, v, w, u),
+        (_sds((4, 1, 64, 64), jnp.bfloat16),) * 4
+        + (_sds((64, 64), jnp.bfloat16),)),
+    "probe_stream_32mb": (
+        lambda x, y: probes._pallas_stream(x, y, block=65536,
+                                           interpret=False),
+        (_sds((2_666_666,)),) * 2),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, shapes = CASES[name]
+    args = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+            for s in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
