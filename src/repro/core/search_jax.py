@@ -693,45 +693,27 @@ def anneal_search(
     tracer = get_tracer()
 
     def call():
-        tb = _device_tables(tables)
-        asg0_full = jnp.asarray(
-            _scatter_population(tables, asg_row, pop, seed))
-        args_tail = (seed, jnp.asarray(steps, jnp.int32),
-                     jnp.asarray(exchange_every, jnp.int32),
-                     jnp.asarray(float(t0)), jnp.asarray(float(t1)))
+        with tracer.span("anneal.upload", "search"):
+            tb = _device_tables(tables)
+            asg0_full = jnp.asarray(
+                _scatter_population(tables, asg_row, pop, seed))
+            args_tail = (seed, jnp.asarray(steps, jnp.int32),
+                         jnp.asarray(exchange_every, jnp.int32),
+                         jnp.asarray(float(t0)), jnp.asarray(float(t1)))
         if kind == "chunked":
-            incumbent = np.inf
             for ci, lo in enumerate(range(0, pop, chunk)):
                 hi = min(lo + chunk, pop)
                 # chunk 0 pays any outstanding jit compile for this
                 # (shape, objective, backend) — later chunks reuse the
                 # executable, so their spans are pure steady state.
                 with tracer.span("anneal.chunk", "search", chunk=ci,
-                                 lo=lo, hi=hi,
-                                 includes_compile=(ci == 0)) as sp:
+                                 lo=lo, hi=hi, includes_compile=(ci == 0)):
                     bo, br = run(tb, jnp.arange(lo, hi, dtype=jnp.int32),
                                  asg0_full[lo:hi], *args_tail)
-                    best_objs[lo:hi] = np.asarray(bo, dtype=np.float64)
-                    best_rows[lo:hi] = np.asarray(br)
-                if tracer.enabled:
-                    chunk_objs = best_objs[lo:hi]
-                    finite = chunk_objs[np.isfinite(chunk_objs)]
-                    # per-move acceptance stays on-device; the fraction
-                    # of chains that ended strictly better than the seed
-                    # schedule is the host-visible acceptance proxy
-                    # (feasible fraction when no seed objective is known).
-                    if init_objective is not None and np.isfinite(
-                            init_objective):
-                        accepted = int((finite < init_objective).sum())
-                    else:
-                        accepted = int(finite.size)
-                    sp.set(accept_rate=round(accepted / (hi - lo), 4))
-                    if finite.size and float(finite.min()) < incumbent:
-                        incumbent = float(finite.min())
-                        tracer.instant(
-                            "anneal.incumbent", "search",
-                            objective=incumbent,
-                            chain=int(lo + np.argmin(best_objs[lo:hi])))
+                    # the pulls block until the device loop has finished
+                    with tracer.span("anneal.wait", "search", chunk=ci):
+                        best_objs[lo:hi] = np.asarray(bo, dtype=np.float64)
+                        best_rows[lo:hi] = np.asarray(br)
             return
         chain_idx = jnp.arange(pop, dtype=jnp.int32)
         with tracer.span("anneal.mesh", "search", fanout=kind,
@@ -745,8 +727,9 @@ def anneal_search(
                 br = br.reshape(pop, tables.w, tables.gmax)
             else:
                 bo, br = run(tb, chain_idx, asg0_full, *args_tail)
-            best_objs[:] = np.asarray(bo, dtype=np.float64)
-            best_rows[:] = np.asarray(br)
+            with tracer.span("anneal.wait", "search"):
+                best_objs[:] = np.asarray(bo, dtype=np.float64)
+                best_rows[:] = np.asarray(br)
 
     with tracer.span("anneal_search", "search", population=pop,
                      steps=steps, island=island, seed=seed,

@@ -37,6 +37,7 @@ from .graph import DNNGraph
 from .simulate import Workload
 from .solver_bb import Solution
 from .solver_greedy import _baseline_pool
+from ..obs import get_tracer
 
 #: fixed defaults when no wall-clock budget drives the auto-tuner.
 DEFAULT_POPULATION = 2048
@@ -198,12 +199,14 @@ def solve(
 ) -> Solution:
     from . import registry, search_jax
 
+    tracer = get_tracer()
     its = list(iterations or [1] * len(graphs))
     deps = list(depends_on or [None] * len(graphs))
     mt = (max(len(g) for g in graphs) if max_transitions is None
           else max_transitions)
-    tables = search_jax.build_tables(platform, graphs, model, mt,
-                                     iterations=its, depends_on=deps)
+    with tracer.span("anneal.tables", "solve"):
+        tables = search_jax.build_tables(platform, graphs, model, mt,
+                                         iterations=its, depends_on=deps)
     entry = registry.resolve_evaluator(evaluator)
 
     tuned = None
@@ -228,16 +231,18 @@ def solve(
     # back to its own duration-greedy single-accelerator init.
     init = init_obj = None
     scalar_evals = 0
-    try:
-        pool = _baseline_pool(platform, graphs, its, deps, mt)
-    except RuntimeError:
-        pool = []
-    for _name, wls in pool:
-        res = entry.simulate(platform, wls, model, record_timeline=False)
-        scalar_evals += 1
-        obj = res.objective(objective)
-        if init_obj is None or obj < init_obj:
-            init, init_obj = [w.assignment for w in wls], obj
+    with tracer.span("anneal.seed", "solve") as sp:
+        try:
+            pool = _baseline_pool(platform, graphs, its, deps, mt)
+        except RuntimeError:
+            pool = []
+        for _name, wls in pool:
+            res = entry.simulate(platform, wls, model, record_timeline=False)
+            scalar_evals += 1
+            obj = res.objective(objective)
+            if init_obj is None or obj < init_obj:
+                init, init_obj = [w.assignment for w in wls], obj
+        sp.set(baselines=len(pool))
 
     out = search_jax.anneal_search(
         tables, objective=objective, seed=seed, population=population,
@@ -248,19 +253,22 @@ def solve(
 
     # The scalar simulator is authoritative: the recorded result (and the
     # objective the Solution carries) never comes from the device.
-    wls = [Workload(g, tuple(a), iterations=it, depends_on=dep)
-           for g, a, it, dep in zip(graphs, out.assignment, its, deps)]
-    res = entry.simulate(platform, wls, model, record_timeline=False)
-    scalar_evals += 1
-    obj = res.objective(objective)
-    if init_obj is not None and init_obj < obj:
-        # float32 ranking can (rarely) prefer a mutant the exact simulator
-        # scores a hair worse than the baseline seed; never regress.
+    with tracer.span("anneal.verify", "solve"):
         wls = [Workload(g, tuple(a), iterations=it, depends_on=dep)
-               for g, a, it, dep in zip(graphs, init, its, deps)]
+               for g, a, it, dep in zip(graphs, out.assignment, its, deps)]
         res = entry.simulate(platform, wls, model, record_timeline=False)
         scalar_evals += 1
         obj = res.objective(objective)
+        if init_obj is not None and init_obj < obj:
+            # float32 ranking can (rarely) prefer a mutant the exact
+            # simulator scores a hair worse than the baseline seed; never
+            # regress.
+            wls = [Workload(g, tuple(a), iterations=it, depends_on=dep)
+                   for g, a, it, dep in zip(graphs, init, its, deps)]
+            res = entry.simulate(platform, wls, model,
+                                 record_timeline=False)
+            scalar_evals += 1
+            obj = res.objective(objective)
 
     params = {
         "seed": int(out.seed),

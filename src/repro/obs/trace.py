@@ -27,6 +27,14 @@ Spans nest per thread: each thread carries its own span stack, and the
 exported events carry that thread's stable track id, so concurrent
 solver threads render as parallel tracks instead of interleaving.
 
+Profiler clock: a wall-clock :class:`Tracer` also enters
+``jax.profiler.TraceAnnotation("repro.<name>")`` around every
+:meth:`Tracer.span` body, so while a ``jax.profiler`` trace runs each
+program span lands in its ``.xplane.pb`` on the device ops' timeline.
+Virtual-clock tracers do not annotate (their times are not the
+profiler's), and jax is never imported here: a process without it has
+no profiler to write into.
+
 Bulk ingestion: :meth:`Tracer.add_events` appends pre-built event
 dicts in one locked call.  The fleet gateway derives its million
 per-request queue/service spans *post hoc* from its flat NumPy record
@@ -38,6 +46,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import json
+import sys
 import threading
 import time
 from typing import Any, Callable, Iterator
@@ -67,13 +76,16 @@ class Span:
     closed span serializes without translation.
     """
 
-    __slots__ = ("name", "cat", "t0", "args")
+    __slots__ = ("name", "cat", "t0", "t1", "args")
 
     def __init__(self, name: str, cat: str, t0: float,
                  args: dict[str, Any]) -> None:
         self.name = name
         self.cat = cat
         self.t0 = t0
+        #: end stamp the body may set (ms on the tracer's clock); the
+        #: tracer reads its clock at exit when it is left ``None``.
+        self.t1: float | None = None
         self.args = args
 
     def set(self, **kwargs: Any) -> "Span":
@@ -127,6 +139,14 @@ class NullTracer:
 #: shared disabled tracer; also the initial global tracer.
 NULL_TRACER = NullTracer()
 
+def _profiler_annotation(name: str):
+    """``jax.profiler.TraceAnnotation("repro.<name>")``; a null context
+    while jax is not imported, since no profiler can run without it."""
+    if "jax" not in sys.modules:
+        return contextlib.nullcontext()
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(f"repro.{name}")
+
 
 class Tracer:
     """Recording tracer.
@@ -178,25 +198,40 @@ class Tracer:
     # -- recording API ----------------------------------------------
 
     @contextlib.contextmanager
-    def span(self, name: str, cat: str = "repro",
-             **args: Any) -> Iterator[Span]:
-        """Record a complete event covering the ``with`` body."""
-        sp = Span(name, cat, self._now(), dict(args))
+    def span(self, name: str, cat: str = "repro", *,
+             t0_ms: float | None = None, **args: Any) -> Iterator[Span]:
+        """Record a complete event covering the ``with`` body.
+
+        A caller that keeps its own record of the same boundaries passes
+        the start it stamped as ``t0_ms`` and sets ``sp.t1`` to the end
+        it stamped, so each boundary is read from the clock once.  A
+        wall-clock tracer also annotates the body for the profiler.
+        """
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
+        sp = Span(name, cat, t0_ms, dict(args))
         stack.append(sp)
         try:
-            yield sp
+            # the clock is read inside the annotation, so both clocks
+            # time the same stretch
+            with (_profiler_annotation(name) if self._wall
+                  else contextlib.nullcontext()):
+                if t0_ms is None:
+                    sp.t0 = self._now()
+                try:
+                    yield sp
+                finally:
+                    if sp.t1 is None:
+                        sp.t1 = self._now()
         finally:
             stack.pop()
-            t1 = self._now()
             track = self._thread_track()
             with self._lock:
                 self._events.append({
                     "ph": "X", "name": sp.name, "cat": sp.cat,
                     "ts": round(sp.t0 * 1e3, 3),
-                    "dur": round((t1 - sp.t0) * 1e3, 3),
+                    "dur": round((sp.t1 - sp.t0) * 1e3, 3),
                     "pid": _PID, "tid": self._tid_locked(track),
                     "args": sp.args,
                 })

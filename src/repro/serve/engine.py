@@ -14,11 +14,19 @@ gateway in :mod:`repro.serve.gateway`) can interleave several engines.  An
 optional ``admission_gate`` lets that multiplexer impose global policies
 (shared memory budget, fairness) on slot admission without changing the
 single-engine control flow.
+
+Every request carries an :class:`AdmissionTiming`: when it was
+submitted, when its admission started, when its prefill and its cache
+splice were dispatched, when its first token reached the host, and the
+bytes its splice wrote.  The engine stamps it on the wall clock the
+``repro.obs`` tracer uses, tracer or not, and its ``engine.*`` spans
+reuse the same stamps.
 """
 from __future__ import annotations
 
 import dataclasses
 import itertools
+import math
 import time
 from collections import deque
 from typing import Callable
@@ -28,7 +36,47 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import Model
-from repro.obs import TENANT_SCHEMA, conform
+from repro.obs import TENANT_SCHEMA, conform, get_tracer
+
+
+def _now_ms() -> float:
+    """The wall clock of a ``repro.obs.Tracer``, in ms."""
+    return time.perf_counter() * 1e3
+
+
+@dataclasses.dataclass
+class AdmissionTiming:
+    """One request's way from the queue to its first token.
+
+    Stamps are ms on :func:`_now_ms`; ``nan`` until reached.
+    ``dispatch_ms`` runs from admission to the first-token sync (the host
+    dispatching the prefill and the splice, which can itself block while
+    the device is busy); ``wait_ms`` is the sync.
+    """
+
+    submitted: float = math.nan
+    #: left the queue for a slot
+    admitted: float = math.nan
+    #: prefill dispatched; the cache splice starts
+    prefilled: float = math.nan
+    #: splice dispatched; the first-token sync starts
+    dispatched: float = math.nan
+    #: first token on the host
+    first_token: float = math.nan
+    #: bytes the splice wrote into the batched cache
+    copy_bytes: int = 0
+
+    @property
+    def queue_ms(self) -> float:
+        return self.admitted - self.submitted
+
+    @property
+    def dispatch_ms(self) -> float:
+        return self.dispatched - self.admitted
+
+    @property
+    def wait_ms(self) -> float:
+        return self.first_token - self.dispatched
 
 
 @dataclasses.dataclass
@@ -39,6 +87,8 @@ class Request:
     eos: int | None = None
     tokens: list[int] = dataclasses.field(default_factory=list)
     done: bool = False
+    timing: AdmissionTiming = dataclasses.field(
+        default_factory=AdmissionTiming)
 
 
 #: canonical per-tenant telemetry keys shared by every serving layer —
@@ -104,6 +154,7 @@ class ServingEngine:
                ) -> Request:
         req = Request(next(self._rid), np.asarray(prompt, np.int32),
                       max_new=max_new, eos=eos)
+        req.timing.submitted = _now_ms()
         self.queue.append(req)
         return req
 
@@ -136,7 +187,7 @@ class ServingEngine:
         })
 
     # ------------------------------------------------------------------
-    def _admit(self):
+    def _admit(self, tracer):
         for slot in range(self.max_slots):
             if self.slots[slot] is not None or not self.queue:
                 continue
@@ -145,29 +196,52 @@ class ServingEngine:
                 self.counters.deferred += 1
                 break
             req = self.queue.popleft()
-            batch = {"token_ids": jnp.asarray(req.prompt)[None]}
-            logits, cache1 = self._prefill(self.params, batch)
-            if self.on_logits is not None:
-                self.on_logits("prefill", logits)
-            # splice the single-request cache into the batched cache.
-            # group caches are stacked (n_groups, batch, ...); tail caches
-            # are (batch, ...).
-            new = dict(self.caches)
-            if self.caches["groups"] is not None:
-                new["groups"] = jax.tree.map(
-                    lambda big, one: big.at[:, slot].set(one[:, 0]),
-                    self.caches["groups"], cache1["groups"])
-            new["tail"] = jax.tree.map(
-                lambda big, one: big.at[slot].set(one[0]),
-                self.caches["tail"], cache1["tail"])
-            self.caches = new
-            tok = int(jnp.argmax(logits[0, -1]))
+            tm = req.timing
+            tm.admitted = _now_ms()
+            with tracer.span("engine.admit", "serve", t0_ms=tm.admitted,
+                             rid=req.rid, slot=slot,
+                             prompt_len=len(req.prompt)) as admit_sp:
+                with tracer.span("engine.prefill", "serve",
+                                 t0_ms=tm.admitted) as sp:
+                    batch = {"token_ids": jnp.asarray(req.prompt)[None]}
+                    logits, cache1 = self._prefill(self.params, batch)
+                    if self.on_logits is not None:
+                        self.on_logits("prefill", logits)
+                    sp.t1 = tm.prefilled = _now_ms()
+                with tracer.span("engine.splice", "serve",
+                                 t0_ms=tm.prefilled) as sp:
+                    tm.copy_bytes = self._splice(slot, cache1)
+                    sp.t1 = tm.dispatched = _now_ms()
+                with tracer.span("engine.first_token", "serve",
+                                 t0_ms=tm.dispatched) as sp:
+                    tok = int(jnp.argmax(logits[0, -1]))
+                    sp.t1 = admit_sp.t1 = tm.first_token = _now_ms()
             req.tokens.append(tok)
             self.slots[slot] = req
             self.lengths[slot] = len(req.prompt)
             self.last_tok[slot] = tok
             self.counters.admitted += 1
             self.counters.tokens_out += 1
+
+    def _splice(self, slot: int, cache1) -> int:
+        """Write a single-request cache into ``slot`` of the batched cache;
+        returns the bytes written.  Group caches are stacked
+        (n_groups, batch, ...), tail caches are (batch, ...).  The eager
+        ``.at[].set`` is out of place, so every replaced leaf is written
+        whole."""
+        new = dict(self.caches)
+        replaced = []
+        if self.caches["groups"] is not None:
+            new["groups"] = jax.tree.map(
+                lambda big, one: big.at[:, slot].set(one[:, 0]),
+                self.caches["groups"], cache1["groups"])
+            replaced += jax.tree.leaves(self.caches["groups"])
+        new["tail"] = jax.tree.map(
+            lambda big, one: big.at[slot].set(one[0]),
+            self.caches["tail"], cache1["tail"])
+        replaced += jax.tree.leaves(self.caches["tail"])
+        self.caches = new
+        return sum(leaf.nbytes for leaf in replaced)
 
     def step(self) -> int:
         """Admit + one batched decode step; returns #active slots.
@@ -177,17 +251,25 @@ class ServingEngine:
         multiplexer can compare observed step latency against a schedule's
         prediction.
         """
-        self._admit()
+        tracer = get_tracer()
+        with tracer.span("engine.step", "serve", step=self.steps):
+            return self._step(tracer)
+
+    def _step(self, tracer) -> int:
+        self._admit(tracer)
         if self.active == 0:
             return 0
-        t0 = time.perf_counter()
-        batch = {"token_ids": jnp.asarray(self.last_tok)[:, None],
-                 "lengths": jnp.asarray(self.lengths)}
-        logits, self.caches = self._decode(self.params, self.caches, batch)
-        if self.on_logits is not None:
-            self.on_logits("decode", logits)
-        toks = np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
-        self.counters.last_step_ms = (time.perf_counter() - t0) * 1e3
+        t0 = _now_ms()
+        with tracer.span("engine.decode", "serve", t0_ms=t0) as sp:
+            batch = {"token_ids": jnp.asarray(self.last_tok)[:, None],
+                     "lengths": jnp.asarray(self.lengths)}
+            logits, self.caches = self._decode(self.params, self.caches,
+                                               batch)
+            if self.on_logits is not None:
+                self.on_logits("decode", logits)
+            toks = np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
+            sp.t1 = t1 = _now_ms()
+        self.counters.last_step_ms = t1 - t0
         self.counters.decode_ms_total += self.counters.last_step_ms
         self.counters.steps += 1
         self.counters.tokens_out += self.active

@@ -183,6 +183,86 @@ class TestChromeExport:
         assert len({tr.events()[0]["tid"], t1, t2}) == 3
 
 
+class TestProfilerAnnotation:
+    """Wall-clock spans reach a running ``jax.profiler`` trace as
+    ``repro.<name>`` host events, on the device ops' clock."""
+
+    @staticmethod
+    def profiled(tmp_path, body):
+        """Run ``body()`` inside a CPU profiler session; returns the
+        ``repro.*`` host events of the ``.xplane.pb`` as
+        ``{name: [duration_us, ...]}``."""
+        import jax
+        from jax.profiler import ProfileData
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            body()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.rglob("*.xplane.pb")
+        found = {}
+        for plane in ProfileData.from_file(str(path)).planes:
+            if not plane.name.startswith("/host"):
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("repro."):
+                        found.setdefault(ev.name, []).append(
+                            ev.duration_ns / 1e3)
+        return found
+
+    def test_wall_span_lands_in_xplane_with_its_duration(self, tmp_path):
+        import time
+        tr = Tracer()
+
+        def body():
+            # long enough that 1% covers the profiler clock's own
+            # re-calibration step (about 0.5 ms, once a session)
+            with tr.span("probe.outer", "test"):
+                time.sleep(0.1)
+                with tr.span("probe.inner"):
+                    time.sleep(0.1)
+        found = self.profiled(tmp_path, body)
+        assert set(found) == {"repro.probe.outer", "repro.probe.inner"}
+        for ev in tr.events():
+            (got,) = found[f"repro.{ev['name']}"]
+            assert abs(got - ev["dur"]) <= max(0.01 * ev["dur"], 50.0)
+
+    def test_virtual_clock_and_null_tracers_write_nothing(self, tmp_path):
+        tr = Tracer(clock=fake_clock())
+
+        def body():
+            with tr.span("probe.virtual"):
+                pass
+            with NULL_TRACER.span("probe.null"):
+                pass
+        assert self.profiled(tmp_path, body) == {}
+        assert [e["name"] for e in tr.events()] == ["probe.virtual"]
+
+    def test_caller_stamps_bound_the_span(self):
+        tr = Tracer(clock=fake_clock())
+        with tr.span("stamped", t0_ms=10.0, rid=3) as sp:
+            sp.t1 = 12.5
+        (ev,) = tr.events()
+        assert (ev["ts"], ev["dur"], ev["args"]) == (1e4, 2.5e3, {"rid": 3})
+
+
+class TestAnnealChunkLoop:
+    def test_emits_no_incumbent_instants_or_accept_rate(self):
+        from repro.core import Scheduler
+        tr = Tracer()
+        set_tracer(tr)
+        sched = Scheduler("xavier-agx")
+        sched.resolve(sched.request(
+            ["vgg19", "resnet101"], solver="anneal", max_transitions=1,
+            population=64, steps=4, island=32))
+        events = tr.events()
+        assert not [e for e in events if e["name"] == "anneal.incumbent"]
+        assert not [e for e in events if e["ph"] == "i"]
+        (chunk,) = [e for e in events if e["name"] == "anneal.chunk"]
+        assert "accept_rate" not in chunk["args"]
+
+
 # ---------------------------------------------------------------------------
 # metrics registry
 # ---------------------------------------------------------------------------
