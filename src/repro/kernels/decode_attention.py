@@ -8,10 +8,13 @@ for decode.  Tiles past a sequence's length are neither read nor computed:
 the lengths arrive by scalar prefetch, and the kv index map clamps to the
 last live tile, so a skipped step re-uses the block already in VMEM.
 
-The cache keeps its ``(B, S, Hkv, D)`` layout and is viewed (for free) as
-``(B, S, Hkv·D)``: a block is ``(1, kv_tile, Hkv·D)``, every head of a tile
-of positions, with lane-dense last dims as Mosaic requires.  Per-head dot
-products become one segmented lane reduction — ``(k ⊙ q) @ E`` with the
+The cache arrives as it is stored (``models/kvcache.py``), lane-dense
+``(B, S, Hkv·D)`` with heads major in the last dim: a block is
+``(1, kv_tile, Hkv·D)``, every head of a tile of positions, with
+lane-dense last dims as Mosaic requires.  A ``(B, S, Hkv, D)`` cache
+would need a reshape here, and on a TPU that is no free view: with D = 64
+XLA stores such a cache sequence-minor and re-lays it out whole.  Per-head
+dot products become one segmented lane reduction — ``(k ⊙ q) @ E`` with the
 0/1 head-indicator matrix ``E`` (Hkv·D, Hkv) on the MXU — and the softmax
 weights are broadcast back onto their head's lanes by ``p @ Eᵀ``.  For GQA
 the query heads are regrouped to ``(group, Hkv·D)`` rows, one per member
@@ -86,13 +89,13 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 @functools.partial(jax.jit, static_argnames=("block_kv", "interpret"))
 def decode_attention(q, k_cache, v_cache, lengths, *, block_kv: int = 512,
                      interpret: bool = False):
-    """q: (B, 1, Hq, D); caches: (B, S, Hkv, D); lengths: (B,) int32."""
+    """q: (B, 1, Hq, D); caches: (B, S, Hkv·D); lengths: (B,) int32."""
     b, sq, hq, d = q.shape
     assert sq == 1, "decode kernel: one query token"
-    _, skv, hkv, dv = v_cache.shape
-    assert d == dv, "decode kernel: one head dim for q, k and v"
+    _, skv, hd = v_cache.shape
+    assert hd % d == 0, "decode kernel: one head dim for q, k and v"
+    hkv = hd // d
     group = hq // hkv
-    hd = hkv * d
     fit = max(8, _TILE_BYTES // (4 * hd) // 8 * 8)
     block_kv = min(block_kv, fit, skv)
     if block_kv < skv:
@@ -128,7 +131,6 @@ def decode_attention(q, k_cache, v_cache, lengths, *, block_kv: int = 512,
             ]),
         out_shape=jax.ShapeDtypeStruct((b, group, hd), q.dtype),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), qg, k_cache.reshape(b, skv, hd),
-      v_cache.reshape(b, skv, hd))
+    )(lengths.astype(jnp.int32), qg, k_cache, v_cache)
     return out.reshape(b, group, hkv, d).transpose(0, 2, 1, 3).reshape(
         b, 1, hq, d)

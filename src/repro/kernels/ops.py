@@ -145,7 +145,16 @@ def _attention_xla(q, k, v, *, causal, window, block_kv):
 # ---------------------------------------------------------------------------
 def decode_attention(q, k_cache, v_cache, lengths, *,
                      backend: Backend = "auto"):
+    """One query token over lane-dense caches. q: (B, 1, Hq, D);
+    k_cache, v_cache: (B, S, Hkv·D), heads major in the last dim, as
+    ``models/kvcache.py`` stores them; lengths: (B,) valid positions."""
     b = resolve("decode_attention", backend)
+    if b in ("pallas", "pallas_interpret"):
+        return _dec.decode_attention(q, k_cache, v_cache, lengths,
+                                     interpret=(b == "pallas_interpret"))
+    d = q.shape[-1]
+    k_cache = k_cache.reshape(*k_cache.shape[:2], -1, d)
+    v_cache = v_cache.reshape(*v_cache.shape[:2], -1, d)
     if b == "stub":
         hq, hkv = q.shape[2], k_cache.shape[2]
         kv = (k_cache.sum(1) + v_cache.sum(1))[:, None]
@@ -154,14 +163,12 @@ def decode_attention(q, k_cache, v_cache, lengths, *,
     if b == "ref":
         return _ref.attention(q, k_cache, v_cache, causal=True,
                               lengths=lengths)
-    if b in ("pallas", "pallas_interpret"):
-        return _dec.decode_attention(q, k_cache, v_cache, lengths,
-                                     interpret=(b == "pallas_interpret"))
     return _decode_xla(q, k_cache, v_cache, lengths)
 
 
 @jax.jit
 def _decode_xla(q, k_cache, v_cache, lengths):
+    """k_cache, v_cache viewed as (B, S, Hkv, D)."""
     B, _, Hq, D = q.shape
     _, S, Hkv, Dv = v_cache.shape
     group = Hq // Hkv

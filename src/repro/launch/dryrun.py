@@ -47,9 +47,10 @@ def cache_logical(model: Model):
     cfg = model.cfg
 
     def kv_layer():
-        d = {"data": ("batch", "kv_seq", "kv_heads", "head_dim")}
+        # lane-dense (B, S, Hkv·D): heads are major in the merged last dim
+        d = {"data": ("batch", "kv_seq", "kv_heads")}
         if cfg.kv_cache_dtype == "int8":
-            d["scale"] = ("batch", "kv_seq", "kv_heads", None)
+            d["scale"] = ("batch", "kv_seq", "kv_heads")
         return d
 
     def one(kind, scanned: bool):

@@ -1,9 +1,13 @@
 """KV-cache storage: full or ring-buffer (local attention), bf16 or int8.
 
-A cache *layer view* is a dict ``{"data": (B, S, Hkv, D)}`` plus, when
-quantized, ``{"scale": (B, S, Hkv, 1) float32}``.  int8 quantization is
-per (position, head) absmax — a beyond-paper memory optimization that keeps
-the 40-kv-head qwen1.5-32b decode_32k cell inside 16 GB/chip (recorded in
+A cache *layer view* is a dict ``{"data": (B, S, Hkv·D)}`` plus, when
+quantized, ``{"scale": (B, S, Hkv) float32}``.  The layout is the one the
+decode kernel reads: every head of a position in one lane-dense row, heads
+major, so the kernel takes the layer as stored.  (A ``(B, S, Hkv, D)``
+layout costs whole-layer re-layout copies on a TPU every decode step.)
+int8 quantization is per (position, head) absmax, one scale broadcast over
+its head's D lanes — a beyond-paper memory optimization that keeps the
+40-kv-head qwen1.5-32b decode_32k cell inside 16 GB/chip (recorded in
 EXPERIMENTS.md §Perf).  Ring buffers exploit softmax permutation-invariance:
 slots are overwritten modulo the window and masking is by valid count only.
 """
@@ -15,9 +19,9 @@ import jax.numpy as jnp
 
 def init_layer(batch: int, seq: int, n_kv: int, d: int, dtype: str):
     if dtype == "int8":
-        return {"data": jnp.zeros((batch, seq, n_kv, d), jnp.int8),
-                "scale": jnp.zeros((batch, seq, n_kv, 1), jnp.float32)}
-    return {"data": jnp.zeros((batch, seq, n_kv, d), jnp.dtype(dtype))}
+        return {"data": jnp.zeros((batch, seq, n_kv * d), jnp.int8),
+                "scale": jnp.zeros((batch, seq, n_kv), jnp.float32)}
+    return {"data": jnp.zeros((batch, seq, n_kv * d), jnp.dtype(dtype))}
 
 
 def size(layer) -> int:
@@ -25,17 +29,25 @@ def size(layer) -> int:
 
 
 def _quant(x):
-    """x: (..., D) -> (int8 data, f32 scale(..., 1))."""
+    """x: (..., Hkv, D) -> (int8 data (..., Hkv·D), f32 scale (..., Hkv))."""
     scale = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
     scale = jnp.maximum(scale, 1e-6) / 127.0
     q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale), -127, 127)
-    return q.astype(jnp.int8), scale
+    return q.astype(jnp.int8).reshape(*x.shape[:-2], -1), scale[..., 0]
+
+
+def _rows(x, dtype):
+    """x: (..., Hkv, D) -> the stored rows (..., Hkv·D) in ``dtype``."""
+    return x.reshape(*x.shape[:-2], -1).astype(dtype)
 
 
 def dequant(layer):
+    """The layer's values, (B, S, Hkv·D): int8 data times its scale."""
     if "scale" in layer:
-        return (layer["data"].astype(jnp.float32) * layer["scale"]
-                ).astype(jnp.bfloat16)
+        data, scale = layer["data"], layer["scale"]
+        heads = data.reshape(*scale.shape, -1).astype(jnp.float32)
+        return (heads * scale[..., None]).reshape(data.shape).astype(
+            jnp.bfloat16)
     return layer["data"]
 
 
@@ -49,7 +61,7 @@ def insert(layer, new, lengths, window: int | None = None):
         return {"data": layer["data"].at[rows, slot].set(q),
                 "scale": layer["scale"].at[rows, slot].set(s)}
     return {"data": layer["data"].at[rows, slot].set(
-        new.astype(layer["data"].dtype))}
+        _rows(new, layer["data"].dtype))}
 
 
 def from_prefill(k, v, capacity: int, dtype: str, window: int | None = None):
@@ -63,22 +75,18 @@ def from_prefill(k, v, capacity: int, dtype: str, window: int | None = None):
     def build(x):
         if window is not None:
             cap = min(window, capacity)
-            layer = init_layer(B, cap, H, D, dtype)
             take = min(S, cap)
-            chunk = x[:, S - take:]                         # last positions
-            pos = (jnp.arange(S - take, S) % cap)
-            if "scale" in layer:
-                q, s = _quant(chunk)
-                return {"data": layer["data"].at[:, pos].set(q),
-                        "scale": layer["scale"].at[:, pos].set(s)}
-            return {"data": layer["data"].at[:, pos].set(
-                chunk.astype(layer["data"].dtype))}
-        layer = init_layer(B, capacity, H, D, dtype)
+            x = x[:, S - take:]                             # last positions
+            at = (slice(None), jnp.arange(S - take, S) % cap)
+        else:
+            cap = capacity
+            at = (slice(None), slice(0, S))
+        layer = init_layer(B, cap, H, D, dtype)
         if "scale" in layer:
             q, s = _quant(x)
-            return {"data": layer["data"].at[:, :S].set(q),
-                    "scale": layer["scale"].at[:, :S].set(s)}
-        return {"data": layer["data"].at[:, :S].set(
-            x.astype(layer["data"].dtype))}
+            return {"data": layer["data"].at[at].set(q),
+                    "scale": layer["scale"].at[at].set(s)}
+        return {"data": layer["data"].at[at].set(
+            _rows(x, layer["data"].dtype))}
 
     return build(k), build(v)

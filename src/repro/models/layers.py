@@ -118,9 +118,11 @@ def attention_block(cfg: ModelConfig, p, rules, x, positions, *,
 
     Train/prefill: ``cache is None`` — self-attention over x; returns
     (y, (k, v)) so prefill can build the cache.
-    Decode: ``cache = (k_cache, v_cache)`` (kvcache.KVLayer views) and
-    ``lengths`` (B,) = tokens already cached; the new token's k/v are
-    inserted at ``lengths`` and attention runs over ``lengths + 1``.
+    Decode: ``cache = (k_cache, v_cache)``, :mod:`~repro.models.kvcache`
+    layer views stored lane-dense as ``(B, S, Hkv·D)``, and ``lengths``
+    (B,) = tokens already cached; the new token's k/v are inserted at
+    ``lengths`` and attention runs over ``lengths + 1`` on the layers as
+    stored, with no re-layout.
     """
     dt = jnp.dtype(cfg.dtype)
     h = rmsnorm(x, p["ln"]).astype(dt)

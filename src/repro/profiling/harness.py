@@ -293,8 +293,9 @@ def _group_runner(cfg, span: Sequence[str], cell, backend: str):
         if kind in ("attn", "local"):
             win = cfg.local_window if kind == "local" else None
             if cell.kind == "decode":
-                o = ops.decode_attention(q, kcache, vcache, lengths,
-                                         backend=backend)
+                o = ops.decode_attention(
+                    q, kcache.reshape(B, kv_len, -1),
+                    vcache.reshape(B, kv_len, -1), lengths, backend=backend)
             else:
                 o = ops.attention(q, kcache[:, :S], vcache[:, :S],
                                   causal=True, window=win, backend=backend)

@@ -67,15 +67,19 @@ class TestDecodeAttention:
     @pytest.mark.parametrize("shape", [(2, 128, 8, 2, 64), (1, 96, 4, 4, 32),
                                        (3, 256, 4, 1, 128)])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    @pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
+    @pytest.mark.parametrize("backend", ["xla", "pallas_interpret", "ref"])
     def test_vs_oracle(self, shape, dtype, backend):
+        """Every backend reads the caches lane-dense, (B, S, Hkv·D), as
+        the model stores them; the oracle gets them 4-D."""
         B, S, Hq, Hkv, D = shape
         q = rand(KEYS[0], (B, 1, Hq, D), dtype)
         k = rand(KEYS[1], (B, S, Hkv, D), dtype)
         v = rand(KEYS[2], (B, S, Hkv, D), dtype)
         lengths = jnp.array([S // 2 + 7 * i + 1 for i in range(B)],
                             jnp.int32) % S + 1
-        got = ops.decode_attention(q, k, v, lengths, backend=backend)
+        got = ops.decode_attention(q, k.reshape(B, S, -1),
+                                   v.reshape(B, S, -1), lengths,
+                                   backend=backend)
         want = ref.attention(q, k, v, causal=True, lengths=lengths)
         np.testing.assert_allclose(
             got.astype(jnp.float32), want.astype(jnp.float32), **tol(dtype))
@@ -91,7 +95,8 @@ class TestDecodeAttention:
         k = rand(KEYS[1], (B, S, Hkv, D), dtype)
         v = rand(KEYS[2], (B, S, Hkv, D), dtype)
         lengths = jnp.array([1, 31, 32, 128], jnp.int32)
-        got = decode_attention(q, k, v, lengths, block_kv=32, interpret=True)
+        got = decode_attention(q, k.reshape(B, S, -1), v.reshape(B, S, -1),
+                               lengths, block_kv=32, interpret=True)
         want = ref.attention(q, k, v, causal=True, lengths=lengths)
         np.testing.assert_allclose(
             got.astype(jnp.float32), want.astype(jnp.float32), **tol(dtype))

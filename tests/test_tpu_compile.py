@@ -12,6 +12,8 @@ every worker imports this file.
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -77,14 +79,14 @@ CASES = {
     "decode_attention_4slots_cap128": (
         lambda q, k, v, n: decode_attention.decode_attention(q, k, v, n),
         (_sds((4, 1, HEADS, HEAD_DIM), jnp.bfloat16),
-         _sds((4, 128, HEADS, HEAD_DIM), jnp.bfloat16),
-         _sds((4, 128, HEADS, HEAD_DIM), jnp.bfloat16),
+         _sds((4, 128, HEADS * HEAD_DIM), jnp.bfloat16),
+         _sds((4, 128, HEADS * HEAD_DIM), jnp.bfloat16),
          _sds((4,), jnp.int32))),
     "decode_attention_gqa_cap2048": (
         lambda q, k, v, n: decode_attention.decode_attention(q, k, v, n),
         (_sds((4, 1, 24, 128), jnp.bfloat16),
-         _sds((4, 2048, 8, 128), jnp.bfloat16),
-         _sds((4, 2048, 8, 128), jnp.bfloat16),
+         _sds((4, 2048, 8 * 128), jnp.bfloat16),
+         _sds((4, 2048, 8 * 128), jnp.bfloat16),
          _sds((4,), jnp.int32))),
     "rglru_scan_prefill": (
         lambda a, b: rglru.rglru_scan(a, b),
@@ -114,3 +116,41 @@ def test_kernel_compiles_for_v5e(one_chip, name):
             for s in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _copies_of_length(hlo: str, n: int) -> list[str]:
+    """The ``copy`` instructions of ``hlo`` whose result has a dim of ``n``."""
+    out = []
+    for line in hlo.splitlines():
+        m = re.search(r"=\s*\w+\[([\d,]*)\]\S*\s+copy\(", line)
+        if m and str(n) in m.group(1).split(","):
+            out.append(line.strip())
+    return out
+
+
+def test_decode_step_keeps_the_cache_layout(one_chip):
+    """stablelm-1.6b at published widths (2 layers, 2 slots of 512): the
+    compiled decode step reads and writes each cache layer as stored, with
+    no whole-layer ``copy`` between the cache, the insert and the kernel."""
+    import dataclasses
+
+    from repro import configs
+    from repro.models import build
+
+    slots, capacity = 2, 512
+    cfg = dataclasses.replace(configs.get("stablelm-1.6b"), n_layers=2,
+                              vocab=1024)
+    model = build(cfg, backend="pallas")
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = place(model.abstract_params())
+    caches = place(jax.eval_shape(lambda: model.init_cache(slots, capacity)))
+    batch = place({"token_ids": _sds((slots, 1), jnp.int32),
+                   "lengths": _sds((slots,), jnp.int32)})
+    hlo = jax.jit(model.decode_step).lower(params, caches, batch
+                                           ).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert _copies_of_length(hlo, capacity) == []
