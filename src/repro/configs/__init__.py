@@ -3,7 +3,8 @@ from importlib import import_module
 
 from .base import (RULES_FSDP_TP, RULES_TP, RULES_TP_2D,  # noqa: F401
                    RULES_ZERO3)
-from .base import SHAPES, ModelConfig, MoEConfig, ShapeCell, cell_supported  # noqa: F401
+from .base import (SHAPES, MLAConfig, ModelConfig, MoEConfig,  # noqa: F401
+                   ShapeCell, YarnConfig, cell_supported)
 
 _MODULES = {
     "recurrentgemma-9b": "recurrentgemma_9b",
@@ -16,6 +17,7 @@ _MODULES = {
     "hubert-xlarge": "hubert_xlarge",
     "dbrx-132b": "dbrx_132b",
     "qwen3-moe-235b-a22b": "qwen3_moe_235b_a22b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 ARCHS = tuple(_MODULES)

@@ -9,6 +9,7 @@ the same family for CPU tests.
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass, field
 from typing import Mapping, Sequence
 
@@ -65,11 +66,100 @@ RULES_ZERO3 = {
 
 @dataclass(frozen=True)
 class MoEConfig:
+    #: experts the router scores (its output width)
     n_experts: int
     top_k: int
     capacity_factor: float = 1.25
     aux_loss_weight: float = 0.01
     router_z_weight: float = 1e-3
+    #: one routed expert's SwiGLU width; 0 -> the model's ``d_ff``
+    d_ff: int = 0
+    #: shared experts every token passes through, run as one SwiGLU of
+    #: ``n_shared * d_ff`` width
+    n_shared: int = 0
+    #: renormalise the top-k gates to sum to 1 (False: raw softmax mass)
+    norm_topk: bool = True
+    #: the experts this chip holds under expert parallelism: the
+    #: contiguous range ``[first_held, first_held + n_held)`` of the
+    #: ``n_experts`` the router scores; 0 -> all of them.  Routing is over
+    #: all experts; what the absent ones would add is left out.
+    first_held: int = 0
+    n_held: int = 0
+
+    def __post_init__(self):
+        if self.n_held == 0:
+            object.__setattr__(self, "n_held", self.n_experts - self.first_held)
+        if not 0 <= self.first_held < self.first_held + self.n_held \
+                <= self.n_experts:
+            raise ValueError("held experts must lie inside the router's range")
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2): keys and values are
+    rebuilt per head from a ``kv_rank``-wide latent, and one
+    ``rope_dim``-wide rotary key is shared by every head."""
+
+    kv_rank: int = 512
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
+
+    @property
+    def q_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+    @property
+    def latent_width(self) -> int:
+        """One cached row: the latent, then the shared rotary key."""
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def cache_width(self) -> int:
+        """A cached row as stored: ``latent_width`` padded with zeros to
+        whole 128-lane tiles, so the row is the minor dimension of the
+        cache's layout on a TPU (a 576-wide minor dimension is not one
+        XLA keeps, and re-laying out the cache costs a copy of it)."""
+        return -(-self.latent_width // 128) * 128
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071), as DeepSeek-V2 states it."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def _corr_dim(self, rotations: float, dim: int, theta: float) -> float:
+        return (dim * math.log(self.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    def ramp_bounds(self, dim: int, theta: float) -> tuple[int, int]:
+        """(low, high): rotary pairs below ``low`` keep their frequency,
+        those above ``high`` are divided by ``factor``."""
+        low = math.floor(self._corr_dim(self.beta_fast, dim, theta))
+        high = math.ceil(self._corr_dim(self.beta_slow, dim, theta))
+        return max(low, 0), min(high, dim - 1)
+
+    def get_mscale(self, mscale: float) -> float:
+        if self.factor <= 1:
+            return 1.0
+        return 0.1 * mscale * math.log(self.factor) + 1.0
+
+    @property
+    def cos_sin_scale(self) -> float:
+        return (self.get_mscale(self.mscale)
+                / self.get_mscale(self.mscale_all_dim))
+
+    @property
+    def attn_scale(self) -> float:
+        """The factor on softmax's ``d^-1/2``: ``mscale(all_dim)^2``."""
+        return self.get_mscale(self.mscale_all_dim) ** 2
 
 
 @dataclass(frozen=True)
@@ -84,8 +174,15 @@ class ModelConfig:
     vocab: int
     d_head: int = 0                 # 0 -> d_model // n_heads
     moe: MoEConfig | None = None
-    #: repeating block pattern; "attn" | "local" | "rglru" | "rwkv".
+    #: repeating block pattern; "attn" | "local" | "rglru" | "rwkv" | "mla".
     block_pattern: tuple[str, ...] = ("attn",)
+    #: latent attention widths, for "mla" layers
+    mla: MLAConfig | None = None
+    #: YaRN scaling of the rotary frequencies; None -> plain rotary
+    rope_scaling: YarnConfig | None = None
+    #: leading layers with a dense ``d_ff`` MLP in place of the MoE, run
+    #: unrolled before the scanned stack
+    first_dense: int = 0
     bidirectional: bool = False     # encoder-only (no causal mask, no decode)
     local_window: int = 2048
     act: str = "swiglu"             # swiglu | squared_relu | gelu
@@ -129,10 +226,14 @@ class ModelConfig:
         if self.n_heads % self.n_kv_heads:
             raise ValueError("n_heads must be a multiple of n_kv_heads")
         for b in self.block_pattern:
-            if b not in ("attn", "local", "rglru", "rwkv"):
+            if b not in ("attn", "local", "rglru", "rwkv", "mla"):
                 raise ValueError(f"unknown block kind {b!r}")
+        if "mla" in self.block_pattern and self.mla is None:
+            raise ValueError("mla layers require MLAConfig")
         if self.family == "moe" and self.moe is None:
             raise ValueError("moe family requires MoEConfig")
+        if self.first_dense and self.moe is None:
+            raise ValueError("leading dense layers precede MoE layers")
 
     # ------------------------------------------------------------------
     @property
@@ -156,7 +257,8 @@ class ModelConfig:
         total = V * d                       # embedding
         if not self.tie_embeddings:
             total += V * d                  # unembedding
-        for kind in self.layer_kinds:
+        n_mats = 3 if self.act == "swiglu" else 2
+        for i, kind in enumerate(self.layer_kinds):
             if kind in ("attn", "local"):
                 total += d * hq * dh + 2 * d * hkv * dh + hq * dh * d
                 if self.qkv_bias:
@@ -169,16 +271,26 @@ class ModelConfig:
             elif kind == "rwkv":
                 total += 4 * d * d + d * d  # r,k,v,g,o projections
                 total += 2 * d              # decay/bonus params per channel
+            elif kind == "mla":
+                m = self.mla
+                total += d * hq * m.q_dim + d * m.latent_width + m.kv_rank
+                total += m.kv_rank * hq * (m.nope_dim + m.v_dim)
+                total += hq * m.v_dim * d
             # channel mix / MLP
-            if self.moe is not None:
-                total += d * self.moe.n_experts  # router
-                n_mats = 3 if self.act == "swiglu" else 2
-                total += self.moe.n_experts * n_mats * d * ff
+            if self.moe is not None and i >= self.first_dense:
+                mo = self.moe
+                total += d * mo.n_experts   # router
+                total += mo.n_held * n_mats * d * self.expert_ff
+                total += n_mats * d * self.expert_ff * mo.n_shared
             else:
-                n_mats = 3 if self.act == "swiglu" else 2
                 total += n_mats * d * ff
             total += 2 * d                  # norms
         return total
+
+    @property
+    def expert_ff(self) -> int:
+        """One routed expert's width."""
+        return (self.moe.d_ff or self.d_ff) if self.moe is not None else 0
 
     def n_active_params(self) -> int:
         """Parameters touched per token (MoE: top-k experts only)."""
@@ -186,10 +298,11 @@ class ModelConfig:
             return self.n_params()
         full = self.n_params()
         n_mats = 3 if self.act == "swiglu" else 2
-        per_layer_experts = self.moe.n_experts * n_mats * self.d_model * self.d_ff
-        active = (self.moe.top_k / self.moe.n_experts) * per_layer_experts
-        return int(full - self.n_layers * per_layer_experts
-                   + self.n_layers * active)
+        mo = self.moe
+        per_layer_experts = mo.n_held * n_mats * self.d_model * self.expert_ff
+        active = (mo.top_k / mo.n_experts) * per_layer_experts
+        n_moe = self.n_layers - self.first_dense
+        return int(full - n_moe * per_layer_experts + n_moe * active)
 
     def reduced(self, **overrides) -> "ModelConfig":
         """Smoke-test sibling: same family/pattern, tiny dims."""
@@ -215,8 +328,15 @@ class ModelConfig:
         if self.moe is not None:
             # generous capacity so reduced-config decode is drop-free and
             # prefill+decode consistency is exact
-            small["moe"] = MoEConfig(n_experts=4, top_k=2,
-                                     capacity_factor=8.0)
+            mo = self.moe
+            small["moe"] = MoEConfig(
+                n_experts=4, top_k=2, capacity_factor=8.0,
+                d_ff=64 if mo.d_ff else 0, n_shared=mo.n_shared,
+                norm_topk=mo.norm_topk)
+        if self.mla is not None:
+            small["mla"] = MLAConfig(kv_rank=32, nope_dim=16, rope_dim=8,
+                                     v_dim=16)
+        small["first_dense"] = min(self.first_dense, 1)
         small.update(overrides)
         return dataclasses.replace(self, **small)
 
@@ -246,7 +366,7 @@ def cell_supported(cfg: ModelConfig, shape: str) -> tuple[bool, str]:
     if cell.kind == "decode" and not cfg.has_decode:
         return False, "encoder-only arch has no decode step"
     if shape == "long_500k":
-        if any(k == "attn" for k in cfg.layer_kinds):
+        if any(k in ("attn", "mla") for k in cfg.layer_kinds):
             return False, ("pure full-attention arch: 512k decode requires "
                            "sub-quadratic attention")
     return True, ""

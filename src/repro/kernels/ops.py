@@ -25,6 +25,8 @@ from repro.obs import get_registry
 
 from . import decode_attention as _dec
 from . import flash_attention as _fa
+from . import mla_decode as _mla
+from . import moe_gmm as _moe
 from . import ref as _ref
 from . import rglru as _rglru
 from . import rwkv6 as _rwkv6
@@ -179,6 +181,63 @@ def _decode_xla(q, k_cache, v_cache, lengths):
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgk,bkhe->bhge", p, v_cache.astype(jnp.float32))
     return out.reshape(B, 1, Hq, Dv).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# latent-attention decode (one token, latent cache, per-sequence lengths)
+# ---------------------------------------------------------------------------
+def mla_decode(q, cache, lengths, *, value_width: int,
+               backend: Backend = "auto"):
+    """Absorbed latent attention for one query token. q: (B, H, W) scaled
+    queries ``[q_n W_uk^T | q_r]``; cache: (B, S, W) rows ``[c | k_r]`` as
+    ``models/kvcache.py`` stores them; lengths: (B,) valid positions.
+    Returns (B, H, value_width), each head's weighted sum of latents."""
+    b = resolve("mla_decode", backend)
+    if b in ("pallas", "pallas_interpret"):
+        return _mla.mla_decode(q, cache, lengths, value_width=value_width,
+                               interpret=(b == "pallas_interpret"))
+    if b == "stub":
+        kv = cache.sum(1)[:, None, :value_width]             # reads the cache
+        scale = (1 + lengths.astype(q.dtype) * 0)[:, None, None]
+        return (q[..., :value_width] * kv * scale).astype(cache.dtype)
+    if b == "ref":
+        return _ref.mla_decode(q, cache, lengths, value_width)
+    return _mla_decode_xla(q, cache, lengths, value_width)
+
+
+@functools.partial(jax.jit, static_argnums=(3,))
+def _mla_decode_xla(q, cache, lengths, value_width):
+    return _ref.mla_decode(q, cache, lengths, value_width).astype(cache.dtype)
+
+
+# ---------------------------------------------------------------------------
+# grouped expert SwiGLU (drop-free MoE dispatch)
+# ---------------------------------------------------------------------------
+def moe_gmm(x, wi_gate, wi, wo, group_sizes, *, backend: Backend = "auto"):
+    """SwiGLU of each held expert over its rows. x: (M, d) rows sorted by
+    expert, ``group_sizes[e]`` of them for expert e; weights (E, d, ff) and
+    (E, ff, d).  Rows past the groups come back as zeros.  (M, d) float32."""
+    b = resolve("moe_gmm", backend)
+    if b in ("pallas", "pallas_interpret"):
+        return _moe.moe_gmm(x, wi_gate, wi, wo, group_sizes,
+                            interpret=(b == "pallas_interpret"))
+    if b == "stub":
+        w = wi.sum(0) + wi_gate.sum(0)                       # reads weights
+        return (x @ w @ wo.sum(0)).astype(jnp.float32)
+    if b == "ref":
+        return _ref.moe_gmm(x, wi_gate, wi, wo, group_sizes)
+    return _moe_gmm_xla(x, wi_gate, wi, wo, group_sizes)
+
+
+@jax.jit
+def _moe_gmm_xla(x, wi_gate, wi, wo, group_sizes):
+    """``jax.lax.ragged_dot`` products: rows past the groups are zeros."""
+    gs = group_sizes.astype(jnp.int32)
+    f32 = jnp.float32
+    up = jax.lax.ragged_dot(x, wi, gs, preferred_element_type=f32)
+    gate = jax.lax.ragged_dot(x, wi_gate, gs, preferred_element_type=f32)
+    act = (jax.nn.silu(gate) * up).astype(x.dtype)
+    return jax.lax.ragged_dot(act, wo, gs, preferred_element_type=f32)
 
 
 # ---------------------------------------------------------------------------

@@ -168,3 +168,33 @@ def rwkv6(r, k, v, w, u, state0=None):
         ys.append(y)
     y_all = jnp.stack(ys, axis=1).astype(v.dtype)
     return y_all, S
+
+
+def mla_decode(q, cache, lengths, value_width):
+    """Reference absorbed latent attention: per head, softmax over the
+    first ``lengths`` cached rows of ``q . row``, then the weighted sum of
+    the rows' first ``value_width`` lanes.  q: (B, H, W); cache (B, S, W)."""
+    qf = q.astype(jnp.float32)
+    cf = cache.astype(jnp.float32)
+    s = jnp.einsum("bhw,bsw->bhs", qf, cf)
+    valid = jnp.arange(cache.shape[1])[None, :] < lengths[:, None]
+    s = jnp.where(valid[:, None, :], s, -1e30)
+    p = jnp.exp(s - s.max(-1, keepdims=True))
+    p = p / p.sum(-1, keepdims=True)
+    return jnp.einsum("bhs,bsv->bhv", p, cf[..., :value_width])
+
+
+def moe_gmm(x, wi_gate, wi, wo, group_sizes):
+    """Reference grouped SwiGLU: row r in group e gets expert e's SwiGLU;
+    rows past the groups are zero."""
+    xf = x.astype(jnp.float32)
+    ends = jnp.cumsum(group_sizes)
+    row = jnp.arange(x.shape[0])
+    grp = jnp.searchsorted(ends, row, side="right")          # (M,)
+    live = grp < wi.shape[0]
+    g = jnp.minimum(grp, wi.shape[0] - 1)
+    up = jnp.einsum("md,mdf->mf", xf, wi[g].astype(jnp.float32))
+    gate = jnp.einsum("md,mdf->mf", xf, wi_gate[g].astype(jnp.float32))
+    act = (gate / (1 + jnp.exp(-gate))) * up
+    out = jnp.einsum("mf,mfd->md", act, wo[g].astype(jnp.float32))
+    return jnp.where(live[:, None], out, 0.0)

@@ -59,6 +59,8 @@ def cache_logical(model: Model):
             lay = kv_layer()
             return {"k": {k: pre + v for k, v in lay.items()},
                     "v": {k: pre + v for k, v in lay.items()}}
+        if kind == "mla":           # one latent row per position
+            return {k: pre + v for k, v in kv_layer().items()}
         if kind == "rglru":
             return {"h": pre + ("batch", "rnn"),
                     "conv": pre + ("batch", None, "rnn")}
@@ -69,13 +71,17 @@ def cache_logical(model: Model):
         raise ValueError(kind)
 
     kinds = cfg.layer_kinds
+    n_lead = cfg.first_dense
     P = len(cfg.block_pattern)
-    n_groups = (len(kinds) // P) if cfg.scan_layers else 0
+    n_groups = ((len(kinds) - n_lead) // P) if cfg.scan_layers else 0
     groups = (tuple(one(cfg.block_pattern[pos], True) for pos in range(P))
               if n_groups else None)
     tail = tuple(one(kinds[i], False)
-                 for i in range(n_groups * P, len(kinds)))
-    return {"groups": groups, "tail": tail}
+                 for i in range(n_lead + n_groups * P, len(kinds)))
+    out = {"groups": groups, "tail": tail}
+    if n_lead:
+        out["lead"] = tuple(one(kinds[i], False) for i in range(n_lead))
+    return out
 
 
 def batch_logical(batch):

@@ -10,6 +10,12 @@ its head's D lanes — a beyond-paper memory optimization that keeps the
 40-kv-head qwen1.5-32b decode_32k cell inside 16 GB/chip (recorded in
 EXPERIMENTS.md §Perf).  Ring buffers exploit softmax permutation-invariance:
 slots are overwritten modulo the window and masking is by valid count only.
+
+A latent-attention (``mla``) layer keeps one such view with a single
+"head" as wide as its row: per position the normed latent ``c_kv`` and
+the shared rotary key, zero-padded to whole lane tiles
+(``MLAConfig.cache_width``), from which every head's keys and values are
+rebuilt (:func:`latent_from_prefill`).
 """
 from __future__ import annotations
 
@@ -64,29 +70,35 @@ def insert(layer, new, lengths, window: int | None = None):
         _rows(new, layer["data"].dtype))}
 
 
+def _build(x, capacity: int, dtype: str, window: int | None):
+    """One cache layer holding ``x``: (B, S, H, D) from position 0."""
+    B, S, H, D = x.shape
+    if window is not None:
+        cap = min(window, capacity)
+        take = min(S, cap)
+        x = x[:, S - take:]                                 # last positions
+        at = (slice(None), jnp.arange(S - take, S) % cap)
+    else:
+        cap = capacity
+        at = (slice(None), slice(0, S))
+    layer = init_layer(B, cap, H, D, dtype)
+    if "scale" in layer:
+        q, s = _quant(x)
+        return {"data": layer["data"].at[at].set(q),
+                "scale": layer["scale"].at[at].set(s)}
+    return {"data": layer["data"].at[at].set(_rows(x, layer["data"].dtype))}
+
+
 def from_prefill(k, v, capacity: int, dtype: str, window: int | None = None):
     """Build cache layers from prefill-computed k, v: (B, S, Hkv, D).
 
     For local attention only the last ``window`` positions are kept (ring
     layout with slot = pos % window so subsequent inserts line up).
     """
-    B, S, H, D = k.shape
+    return (_build(k, capacity, dtype, window),
+            _build(v, capacity, dtype, window))
 
-    def build(x):
-        if window is not None:
-            cap = min(window, capacity)
-            take = min(S, cap)
-            x = x[:, S - take:]                             # last positions
-            at = (slice(None), jnp.arange(S - take, S) % cap)
-        else:
-            cap = capacity
-            at = (slice(None), slice(0, S))
-        layer = init_layer(B, cap, H, D, dtype)
-        if "scale" in layer:
-            q, s = _quant(x)
-            return {"data": layer["data"].at[at].set(q),
-                    "scale": layer["scale"].at[at].set(s)}
-        return {"data": layer["data"].at[at].set(
-            _rows(x, layer["data"].dtype))}
 
-    return build(k), build(v)
+def latent_from_prefill(rows, capacity: int, dtype: str):
+    """A latent cache layer from prefill-computed rows: (B, S, W)."""
+    return _build(rows[:, :, None], capacity, dtype, None)

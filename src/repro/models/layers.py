@@ -72,14 +72,32 @@ def _rms_bwd(eps, res, g):
 rmsnorm.defvjp(_rms_fwd, _rms_bwd)
 
 
-def rope(x, positions, theta: float):
-    """x: (B, S, H, D); positions: (B, S) or (S,)."""
-    d = x.shape[-1]
+def yarn_freq(d: int, theta: float, yarn):
+    """YaRN's rotary frequencies for a ``d``-wide rotary vector: pairs
+    below the ramp keep ``theta^(-2i/d)``, pairs above it are divided by
+    ``yarn.factor``, linearly in between."""
     half = d // 2
     freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    low, high = yarn.ramp_bounds(d, theta)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return freq / yarn.factor * ramp + freq * (1.0 - ramp)
+
+
+def rope(x, positions, theta: float, yarn=None):
+    """x: (B, S, H, D); positions: (B, S) or (S,).  ``yarn``: a
+    :class:`~repro.configs.base.YarnConfig` scaling the frequencies."""
+    d = x.shape[-1]
+    half = d // 2
+    if yarn is None:
+        freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freq = yarn_freq(d, theta, yarn)
     ang = positions.astype(jnp.float32)[..., None] * freq      # (B,S,half)
     cos = jnp.cos(ang)[..., None, :]                           # (B,S,1,half)
     sin = jnp.sin(ang)[..., None, :]
+    if yarn is not None and yarn.cos_sin_scale != 1.0:
+        cos, sin = cos * yarn.cos_sin_scale, sin * yarn.cos_sin_scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
     return out.astype(x.dtype)
@@ -209,6 +227,12 @@ def _act(cfg, gate, up):
 def mlp_block(cfg: ModelConfig, p, rules, x):
     dt = jnp.dtype(cfg.dtype)
     h = rmsnorm(x, p["ln"]).astype(dt)
+    return x + mlp(cfg, p, rules, h)
+
+
+def mlp(cfg: ModelConfig, p, rules, h):
+    """The MLP of ``p`` on normed activations ``h``, without residual."""
+    dt = h.dtype
     up = h @ sharding.weight_use(p["wi"].astype(dt), rules,
                                  ("embed", "mlp"))
     gate = (h @ sharding.weight_use(p["wi_gate"].astype(dt), rules,
@@ -218,8 +242,7 @@ def mlp_block(cfg: ModelConfig, p, rules, x):
     a = sharding.constrain(a, rules, ("batch", "seq", "mlp"))
     y = a @ sharding.weight_use(p["wo"].astype(dt), rules,
                                 ("mlp", "embed"))
-    y = sharding.constrain(y, rules, ("batch", "seq", "embed"))
-    return x + y
+    return sharding.constrain(y, rules, ("batch", "seq", "embed"))
 
 
 # ---------------------------------------------------------------------------

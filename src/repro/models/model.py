@@ -53,19 +53,23 @@ class Model:
         return logits, aux
 
     def prefill(self, params, batch, capacity: int | None = None):
-        """Returns (last-token logits, decode caches).
+        """Returns (last-token logits, decode caches, stats).
 
         ``capacity``: cache slots to allocate (default prompt length + 64
-        so a generation loop can append without reallocation)."""
+        so a generation loop can append without reallocation).  ``stats``
+        holds the served MoE layers' ``moe_rows`` (layers, held experts)
+        and ``moe_dropped``; it is empty for a model without MoE."""
         seq = (batch["embeds"] if self.cfg.embeds_only
                else batch["token_ids"]).shape[1]
-        logits, caches, _ = transformer.forward(
+        logits, caches, aux = transformer.forward(
             self.cfg, params, batch, self.rules, backend=self.backend,
             collect_kv=True, last_only=True,
             cache_capacity=capacity or seq + 64)
-        return logits, caches
+        return logits, caches, {k: aux[k] for k in transformer.STATS
+                                if k in aux}
 
     def decode_step(self, params, caches, batch):
+        """Returns (logits, new caches, stats), ``stats`` as ``prefill``'s."""
         return transformer.decode_step(self.cfg, params, caches, batch,
                                        self.rules, backend=self.backend)
 
@@ -117,6 +121,10 @@ class Model:
         dh_rwkv = cfg.d_model // H
 
         def one(kind):
+            if kind == "mla":
+                return kvcache.init_layer(batch, capacity, 1,
+                                          cfg.mla.cache_width,
+                                          cfg.kv_cache_dtype)
             if kind in ("attn", "local"):
                 cap = (min(cfg.local_window, capacity) if kind == "local"
                        else capacity)
@@ -140,19 +148,26 @@ class Model:
             raise ValueError(kind)
 
         kinds = cfg.layer_kinds
+        n_lead = cfg.first_dense
         P = len(cfg.block_pattern)
-        n_groups = (len(kinds) // P) if cfg.scan_layers else 0
+        n_groups = ((len(kinds) - n_lead) // P) if cfg.scan_layers else 0
         n_scanned = n_groups * P
         groups = None
         if n_groups:
+            # zeros of the stacked shape at once: stacking per-layer arrays
+            # holds them and their copies on the device beside the stack
             groups = tuple(
-                jax.tree.map(lambda *xs: jnp.stack(xs, 0),
-                             *[one(cfg.block_pattern[pos])
-                               for _ in range(n_groups)])
+                jax.tree.map(lambda a: jnp.zeros((n_groups, *a.shape),
+                                                 a.dtype),
+                             jax.eval_shape(functools.partial(
+                                 one, cfg.block_pattern[pos])))
                 for pos in range(P))
-        tail = tuple(one(kinds[i]) for i in range(n_scanned, len(kinds)))
-        return {"groups": groups if groups is not None else None,
-                "tail": tail}
+        tail = tuple(one(kinds[i])
+                     for i in range(n_lead + n_scanned, len(kinds)))
+        caches = {"groups": groups, "tail": tail}
+        if n_lead:
+            caches["lead"] = tuple(one(kinds[i]) for i in range(n_lead))
+        return caches
 
 
 def build(cfg: ModelConfig, **kw) -> Model:

@@ -1,13 +1,30 @@
-"""Mixture-of-Experts channel mixing: top-k routing, permutation dispatch.
+"""Mixture-of-Experts channel mixing: top-k routing over every expert,
+computed by the experts this chip holds, plus optional shared experts.
 
-Sort-based (gshard-one-hot-free) dispatch: token·expert assignments are
-sorted by expert id, positions within each expert group come from a cumsum
-over bincounts, tokens beyond the per-expert capacity are dropped (their
-combine weight is zero — the residual path carries them), and expert FFNs
-run as one batched einsum over the (experts, capacity, d) buffer.  Experts
-shard over the "experts" logical axis (EP over the model mesh axis); the
-inner FFN dim can additionally shard over "expert_mlp" (2-D sharding for
-huge serving models).
+Two dispatches, chosen by entry point:
+
+* Training and full-sequence evaluation (:func:`moe_block`, reached from
+  ``transformer.forward`` without a cache) use sort-based capacity
+  dispatch: token·expert assignments are sorted by expert id, positions
+  within each expert group come from a cumsum over bincounts, tokens
+  beyond the per-expert capacity ``round(T·k·capacity_factor/E)`` are
+  **dropped** (their combine weight is zero; the residual path carries
+  them), and expert FFNs run as one batched einsum over the (experts,
+  capacity, d) buffer.  Experts shard over the "experts" logical axis
+  (EP over the model mesh axis); the inner FFN dim can additionally
+  shard over "expert_mlp" (2-D sharding for huge serving models).
+* Serving (:func:`moe_block_served`, reached from ``Model.prefill`` and
+  ``Model.decode_step``) **drops nothing**: every assignment to a held
+  expert is computed by a grouped matmul over the rows sorted by expert
+  (``ops.moe_gmm``), so a served token never depends on which requests
+  share its batch.  It reports the rows each held expert computed.
+
+The router always scores all ``n_experts``; a chip holding the share
+``[first_held, first_held + n_held)`` computes those experts' part of the
+result and leaves out what the absent ones would add (expert parallelism
+without its exchange).  Gates are the softmax mass of the top k,
+renormalised unless ``norm_topk`` is off.  Shared experts run on every
+token as one SwiGLU of ``n_shared`` experts' width.
 """
 from __future__ import annotations
 
@@ -16,8 +33,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops
 from . import sharding
-from .layers import rmsnorm, dense_init
+from .layers import dense_init, mlp, rmsnorm
 
 
 def _ffn(cfg, x_in, wi, wg, wo):
@@ -111,24 +129,95 @@ def _moe_ep_shardmap(cfg, h, gate_vals, expert_idx, wi, wg, wo, rules, mesh):
 
 
 def init_moe(cfg: ModelConfig, key):
-    d, ff, E = cfg.d_model, cfg.d_ff, cfg.moe.n_experts
+    mo = cfg.moe
+    d, ff, E, En = cfg.d_model, cfg.expert_ff, mo.n_experts, mo.n_held
     pdt = jnp.dtype(cfg.param_dtype)
-    ks = jax.random.split(key, 4)
+    ks = jax.random.split(key, 5)
     p, s = {}, {}
     p["ln"], s["ln"] = jnp.zeros((d,), pdt), ("embed",)
     p["router"], s["router"] = dense_init(ks[0], (d, E),
                                           ("embed", "experts"), pdt)
     if cfg.act == "swiglu":
         p["wi_gate"], s["wi_gate"] = dense_init(
-            ks[1], (E, d, ff), ("experts", "embed", "expert_mlp"), pdt,
+            ks[1], (En, d, ff), ("experts", "embed", "expert_mlp"), pdt,
             fan_in_axes=(1,))
-    p["wi"], s["wi"] = dense_init(ks[2], (E, d, ff),
+    p["wi"], s["wi"] = dense_init(ks[2], (En, d, ff),
                                   ("experts", "embed", "expert_mlp"), pdt,
                                   fan_in_axes=(1,))
-    p["wo"], s["wo"] = dense_init(ks[3], (E, ff, d),
+    p["wo"], s["wo"] = dense_init(ks[3], (En, ff, d),
                                   ("experts", "expert_mlp", "embed"), pdt,
                                   fan_in_axes=(1,))
+    if mo.n_shared:
+        sff = mo.n_shared * ff
+        kg, ki, ko = jax.random.split(ks[4], 3)
+        sh, ss = {}, {}
+        sh["wi_gate"], ss["wi_gate"] = dense_init(kg, (d, sff),
+                                                  ("embed", "mlp"), pdt)
+        sh["wi"], ss["wi"] = dense_init(ki, (d, sff), ("embed", "mlp"), pdt)
+        sh["wo"], ss["wo"] = dense_init(ko, (sff, d), ("mlp", "embed"), pdt)
+        p["shared"], s["shared"] = sh, ss
     return p, s
+
+
+def _route(cfg: ModelConfig, p, h):
+    """Router logits and softmax over all experts, and the top-k gates
+    and expert ids of each row of ``h`` (T, d)."""
+    mo = cfg.moe
+    logits = jnp.dot(h.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)                      # (T, E)
+    gate_vals, expert_idx = jax.lax.top_k(probs, mo.top_k)       # (T, k)
+    if mo.norm_topk:
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9)
+    return logits, probs, gate_vals, expert_idx
+
+
+def _shared(cfg: ModelConfig, p, rules, h, shape):
+    """The shared experts' output for normed rows ``h``, as ``shape``."""
+    if "shared" not in p:
+        return 0.0
+    return mlp(cfg, p["shared"], rules, h.reshape(shape))
+
+
+def moe_block_served(cfg: ModelConfig, p, rules, x, *, backend="auto"):
+    """Drop-free MoE for serving. x: (B, S, d) -> (x + y, stats) with
+    ``stats["moe_rows"]`` (n_held,) int32, the rows each held expert
+    computed, and ``stats["moe_dropped"]``, the held assignments left
+    uncomputed (0: every one is)."""
+    mo = cfg.moe
+    if cfg.act != "swiglu":
+        raise ValueError("served MoE experts are SwiGLU")
+    dt = jnp.dtype(cfg.dtype)
+    B, S, d = x.shape
+    T, k, En = B * S, mo.top_k, mo.n_held
+    h = rmsnorm(x, p["ln"]).astype(dt)
+    with jax.named_scope("moe"):
+        _, _, gate_vals, expert_idx = _route(cfg, p, h.reshape(T, d))
+        local = expert_idx.reshape(T * k) - mo.first_held
+        held = (local >= 0) & (local < En)
+        group = jnp.where(held, local, En)          # rows held elsewhere last
+        order = jnp.argsort(group, stable=True)
+        sizes = (group[:, None] == jnp.arange(En)[None]).sum(
+            0, dtype=jnp.int32)
+        tok = order // k
+        rows = h.reshape(T, d)[tok]
+        W = [sharding.weight_use(p[n].astype(dt), rules, lg) for n, lg in (
+            ("wi_gate", ("experts", "embed", "expert_mlp")),
+            ("wi", ("experts", "embed", "expert_mlp")),
+            ("wo", ("experts", "expert_mlp", "embed")))]
+        out = ops.moe_gmm(rows, *W, sizes, backend=backend)      # (T·k, d)
+        # back to (token, choice) order: a gather, where a scatter-add
+        # would serialise on the TPU; rows held elsewhere are zeros
+        out = out[jnp.argsort(order)].reshape(T, k, d)
+        gate = jnp.where(held.reshape(T, k), gate_vals, 0.0)
+        y = (out * gate[..., None]).sum(1)        # float32, not an MXU pass
+        y = y.reshape(B, S, d).astype(dt) + _shared(cfg, p, rules, h,
+                                                    (B, S, d))
+    y = sharding.constrain(y, rules, ("batch", "seq", "embed"))
+    stats = {"moe_rows": sizes,
+             "moe_dropped": jnp.sum(held, dtype=jnp.int32) - jnp.sum(sizes)}
+    return x + y, stats
 
 
 def moe_block(cfg: ModelConfig, p, rules, x):
@@ -148,7 +237,7 @@ def moe_block(cfg: ModelConfig, p, rules, x):
     dt = jnp.dtype(cfg.dtype)
     B, S, d = x.shape
     T = B * S
-    E, k = mo.n_experts, mo.top_k
+    E, k, En = mo.n_experts, mo.top_k, mo.n_held
     mesh = sharding._current_mesh()
     tp = sharding.resolved_size(rules, "experts")
     dp = sharding.resolved_size(rules, "batch")
@@ -157,11 +246,8 @@ def moe_block(cfg: ModelConfig, p, rules, x):
     T_loc = T // dp
 
     h = rmsnorm(x, p["ln"]).astype(dt).reshape(T, d)
-    logits = (h.astype(jnp.float32) @ p["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)                      # (T, E)
-    gate_vals, expert_idx = jax.lax.top_k(probs, k)              # (T, k)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
+    logits, probs, gate_vals, expert_idx = _route(cfg, p, h)
+    shared = _shared(cfg, p, rules, h, (B, S, d))
 
     # ---- aux losses (load balance + router z) ----
     me = probs.mean(axis=0)                                      # (E,)
@@ -175,7 +261,7 @@ def moe_block(cfg: ModelConfig, p, rules, x):
     # (T ~ batch) the jnp path's scatters are a few MB and the 2-D expert
     # weight sharding (expert_mlp -> data) must stay resident.
     if (mesh is not None and tp > 1 and E % tp == 0 and T % dp == 0
-            and (T // dp) % tp == 0 and T // dp >= 2048):
+            and (T // dp) % tp == 0 and T // dp >= 2048 and En == E):
         wi = sharding.weight_use(p["wi"].astype(dt), rules,
                                  ("experts", "embed", "expert_mlp"))
         wg = (sharding.weight_use(p["wi_gate"].astype(dt), rules,
@@ -185,21 +271,24 @@ def moe_block(cfg: ModelConfig, p, rules, x):
                                  ("experts", "expert_mlp", "embed"))
         y = _moe_ep_shardmap(cfg, h, gate_vals, expert_idx, wi, wg, wo,
                              rules, mesh)
-        y = y.reshape(B, S, d)
+        y = y.reshape(B, S, d) + shared
         y = sharding.constrain(y, rules, ("batch", "seq", "embed"))
         return x + y, {"moe_aux": aux, "moe_z": zloss}
 
     # ---- block-local sort-based dispatch with per-block capacity ----
     cap = int(max(1, round(T_loc * k * mo.capacity_factor / E)))
     h_blk = h.reshape(dp, T_loc, d)
-    flat_e = expert_idx.reshape(dp, T_loc * k)
+    flat_e = expert_idx.reshape(dp, T_loc * k) - mo.first_held
+    # assignments to experts held elsewhere sort last and are left out
+    flat_e = jnp.where((flat_e >= 0) & (flat_e < En), flat_e, En)
     order = jnp.argsort(flat_e, axis=1)                          # per block
     sorted_e = jnp.take_along_axis(flat_e, order, axis=1)
-    counts = (sorted_e[:, :, None] == jnp.arange(E)[None, None]).sum(1)
-    starts = jnp.cumsum(counts, axis=1) - counts                 # (dp, E)
+    counts = (sorted_e[:, :, None] == jnp.arange(En)[None, None]).sum(1)
+    starts = jnp.cumsum(counts, axis=1) - counts                 # (dp, En)
     pos = (jnp.arange(T_loc * k)[None]
-           - jnp.take_along_axis(starts, sorted_e, axis=1))
-    keep = pos < cap
+           - jnp.take_along_axis(starts, jnp.minimum(sorted_e, En - 1),
+                                 axis=1))
+    keep = (pos < cap) & (sorted_e < En)
     tok_loc = order // k                                         # (dp, Tk)
     blk = jnp.broadcast_to(jnp.arange(dp)[:, None], tok_loc.shape)
 
@@ -207,7 +296,7 @@ def moe_block(cfg: ModelConfig, p, rules, x):
     write_c = jnp.where(keep, pos, 0)
     src = jnp.take_along_axis(h_blk, tok_loc[..., None], axis=1)
     src = jnp.where(keep[..., None], src, 0)
-    buf = jnp.zeros((E, dp, cap, d), dt)
+    buf = jnp.zeros((En, dp, cap, d), dt)
     buf = buf.at[write_e, blk, write_c].add(src.astype(dt))
     buf = sharding.constrain(buf, rules, ("experts", "batch", None, "embed"))
 
@@ -239,6 +328,6 @@ def moe_block(cfg: ModelConfig, p, rules, x):
         gate_vals.reshape(dp, T_loc * k), order, axis=1)
     y = jnp.zeros((dp, T_loc, d), dt).at[blk, tok_loc].add(
         gathered * gates_sorted[..., None].astype(dt))
-    y = y.reshape(B, S, d)
+    y = y.reshape(B, S, d) + shared
     y = sharding.constrain(y, rules, ("batch", "seq", "embed"))
     return x + y, {"moe_aux": aux, "moe_z": zloss}

@@ -4,13 +4,20 @@ The repeating ``cfg.block_pattern`` (e.g. ("rglru","rglru","attn") for
 recurrentgemma) defines a *supergroup*; parameters of all full supergroups
 are stacked on a leading group axis and executed with ``jax.lax.scan``
 (compact HLO, fast SPMD compile); pattern-remainder tail layers run
-unrolled.  Every layer kind exposes the same interface:
+unrolled.  A model with ``cfg.first_dense`` leading dense-MLP layers (an
+MoE model whose first layers have no experts) runs those unrolled before
+the scan, under the params and cache key ``"lead"``, which only such a
+model has.  Every layer kind exposes the same interface:
 
     apply_layer(cfg, kind, params, rules, x, positions,
                 cache=None, lengths=None, backend) -> (x, new_cache, aux)
 
-with cache pytrees per kind (attention: kv cache views; rglru: h + conv
-state; rwkv: matrix state + token-shift carries).
+with cache pytrees per kind (attention: kv cache views; mla: one latent
+cache view; rglru: h + conv state; rwkv: matrix state + token-shift
+carries).  The serving entry points (prefill, decode) run MoE layers
+drop-free and return what each held expert computed; training and
+full-sequence evaluation run the capacity dispatch
+(:mod:`repro.models.moe`).
 """
 from __future__ import annotations
 
@@ -20,18 +27,26 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from . import kvcache, layers, moe, recurrent
+from . import kvcache, layers, mla, moe, recurrent
+
+#: per-layer statistics of the served MoE, collected layer by layer (the
+#: other aux entries are losses, summed over layers)
+STATS = ("moe_rows", "moe_dropped")
 
 
 # ---------------------------------------------------------------------------
 # per-layer init / apply
 # ---------------------------------------------------------------------------
 
-def init_layer(cfg: ModelConfig, kind: str, key):
+def init_layer(cfg: ModelConfig, kind: str, key, dense: bool = False):
+    """One layer's (params, specs); ``dense`` gives an MoE model's layer a
+    dense ``d_ff`` MLP (its leading layers)."""
     kt, kc = jax.random.split(key)
     p, s = {}, {}
     if kind in ("attn", "local"):
         p["t"], s["t"] = layers.init_attention(cfg, kt)
+    elif kind == "mla":
+        p["t"], s["t"] = mla.init_mla(cfg, kt)
     elif kind == "rglru":
         p["t"], s["t"] = recurrent.init_rglru(cfg, kt)
     elif kind == "rwkv":
@@ -39,7 +54,7 @@ def init_layer(cfg: ModelConfig, kind: str, key):
     else:
         raise ValueError(kind)
     if kind != "rwkv":                     # rwkv carries its own channel mix
-        if cfg.moe is not None:
+        if cfg.moe is not None and not dense:
             p["c"], s["c"] = moe.init_moe(cfg, kc)
         else:
             p["c"], s["c"] = layers.init_mlp(cfg, kc)
@@ -51,6 +66,7 @@ def apply_layer(cfg: ModelConfig, kind: str, p, rules, x, positions, *,
                 cache_capacity=None):
     aux = {}
     new_cache = None
+    served = cache is not None or collect_kv
     if kind in ("attn", "local"):
         att_cache = None if cache is None else (cache["k"], cache["v"])
         x, kv = layers.attention_block(
@@ -64,6 +80,15 @@ def apply_layer(cfg: ModelConfig, kind: str, p, rules, x, positions, *,
                 kv[0], kv[1], cap, cfg.kv_cache_dtype,
                 cfg.local_window if kind == "local" else None)
             new_cache = {"k": kc, "v": vc}
+    elif kind == "mla":
+        x, rows = mla.mla_block(cfg, p["t"], rules, x, positions,
+                                cache=cache, lengths=lengths,
+                                backend=backend)
+        if cache is not None:
+            new_cache = rows
+        elif collect_kv:
+            new_cache = kvcache.latent_from_prefill(
+                rows, cache_capacity or x.shape[1], cfg.kv_cache_dtype)
     elif kind == "rglru":
         x, st = recurrent.rglru_block(cfg, p["t"], rules, x,
                                       state=cache, backend=backend)
@@ -73,11 +98,31 @@ def apply_layer(cfg: ModelConfig, kind: str, p, rules, x, positions, *,
                                      state=cache, backend=backend)
         new_cache = st if (cache is not None or collect_kv) else None
     if "c" in p:
-        if cfg.moe is not None:
-            x, aux = moe.moe_block(cfg, p["c"], rules, x)
-        else:
+        if "router" not in p["c"]:
             x = layers.mlp_block(cfg, p["c"], rules, x)
+        elif served:
+            x, aux = moe.moe_block_served(cfg, p["c"], rules, x,
+                                          backend=backend)
+        else:
+            x, aux = moe.moe_block(cfg, p["c"], rules, x)
     return x, new_cache, aux
+
+
+def _split_aux(aux):
+    """(losses, per-layer statistics) of one layer's aux."""
+    return ({k: v for k, v in aux.items() if k not in STATS},
+            {k: v for k, v in aux.items() if k in STATS})
+
+
+def _gather_stats(per_layer):
+    """One dict of the served MoE statistics over the layers: each a list
+    of per-layer values or of scan-stacked ones."""
+    if not per_layer:
+        return {}
+    rows = jnp.concatenate([s["moe_rows"].reshape(-1, s["moe_rows"].shape[-1])
+                            for s in per_layer], axis=0)
+    dropped = sum(jnp.sum(s["moe_dropped"]) for s in per_layer)
+    return {"moe_rows": rows, "moe_dropped": dropped}
 
 
 # ---------------------------------------------------------------------------
@@ -89,11 +134,13 @@ def _stack(trees):
 
 
 def init_stack(cfg: ModelConfig, key):
-    """Returns (params, specs).  params = {"emb", "groups", "tail"}."""
+    """Returns (params, specs).  params = {"emb", "groups", "tail"}, and
+    "lead" (the leading dense layers) where ``cfg.first_dense``."""
     kinds = cfg.layer_kinds
+    n_lead = cfg.first_dense
+    body = kinds[n_lead:]
     P = len(cfg.block_pattern) if cfg.scan_layers else 1
-    pattern = cfg.block_pattern if cfg.scan_layers else (None,)
-    n_groups = len(kinds) // P if cfg.scan_layers else 0
+    n_groups = len(body) // P if cfg.scan_layers else 0
     n_scanned = n_groups * P
 
     keys = jax.random.split(key, len(kinds) + 1)
@@ -101,19 +148,22 @@ def init_stack(cfg: ModelConfig, key):
     params = {"emb": p_emb}
     specs = {"emb": s_emb}
 
+    if n_lead:
+        lead = [init_layer(cfg, kinds[li], keys[li], dense=True)
+                for li in range(n_lead)]
+        params["lead"] = tuple(lp for lp, _ in lead)
+        specs["lead"] = tuple(ls for _, ls in lead)
+
     if cfg.scan_layers and n_groups > 0:
-        groups, gspecs = [], None
+        groups, gspecs = [], []
         for pos in range(P):
             per_pos = []
             for g in range(n_groups):
-                li = g * P + pos
-                lp, ls = init_layer(cfg, kinds[li], keys[li])
+                li = n_lead + g * P + pos
+                lp, ls = init_layer(cfg, cfg.block_pattern[pos], keys[li])
                 per_pos.append(lp)
                 gspecs_pos = ls
-            stacked = _stack(per_pos)
-            groups.append(stacked)
-            if gspecs is None:
-                gspecs = []
+            groups.append(_stack(per_pos))
             # prepend the scan ("layers") axis to every logical tuple
             gspecs.append(jax.tree.map(
                 lambda lg: ("layers",) + lg, gspecs_pos,
@@ -127,7 +177,7 @@ def init_stack(cfg: ModelConfig, key):
         specs["groups"] = ()
 
     tail_p, tail_s = [], []
-    for li in range(n_scanned, len(kinds)):
+    for li in range(n_lead + n_scanned, len(kinds)):
         lp, ls = init_layer(cfg, kinds[li], keys[li])
         tail_p.append(lp)
         tail_s.append(ls)
@@ -149,7 +199,9 @@ def forward(cfg: ModelConfig, params, batch, rules, *, backend="auto",
             collect_kv=False, last_only=False, cache_capacity=None):
     """Full-sequence forward (train / prefill).
 
-    Returns (logits, caches, aux) — caches is None unless collect_kv.
+    Returns (logits, caches, aux) — caches is None unless collect_kv, the
+    prefill entry point, which also serves MoE layers drop-free and puts
+    their per-layer statistics (``STATS``) in aux.
     """
     x = layers.embed_tokens(cfg, params["emb"], rules, batch)
     B, S = x.shape[:2]
@@ -157,12 +209,36 @@ def forward(cfg: ModelConfig, params, batch, rules, *, backend="auto",
     kinds = cfg.layer_kinds
     P = len(cfg.block_pattern)
     aux_total = {"moe_aux": 0.0, "moe_z": 0.0}
+    stats = []
+
+    def add(aux):
+        losses, st = _split_aux(aux)
+        for k in losses:
+            aux_total[k] = aux_total.get(k, 0.0) + losses[k]
+        if st:
+            stats.append(st)
+
+    def unrolled(x, lp, kind):
+        def fn(x_, lp_):
+            return apply_layer(cfg, kind, lp_, rules, x_, positions,
+                               collect_kv=collect_kv, backend=backend,
+                               cache_capacity=cache_capacity)
+
+        if cfg.remat:   # cost-parity with the checkpointed scan groups
+            fn = jax.checkpoint(fn)
+        return fn(x, lp)
+
+    lead_caches = []
+    for i, lp in enumerate(params.get("lead", ())):
+        x, cache, aux = unrolled(x, lp, kinds[i])
+        lead_caches.append(cache)
+        add(aux)
 
     group_caches = None
     if params["groups"]:
         def group_body(carry, group_params):
             x, aux_in = carry
-            new_caches = []
+            new_caches, new_stats = [], []
             for pos in range(P):
                 kind = cfg.block_pattern[pos]
                 x, cache, aux = apply_layer(
@@ -170,76 +246,95 @@ def forward(cfg: ModelConfig, params, batch, rules, *, backend="auto",
                     collect_kv=collect_kv, backend=backend,
                     cache_capacity=cache_capacity)
                 new_caches.append(cache)
-                for k in aux:
-                    aux_in = dict(aux_in, **{k: aux_in.get(k, 0.0) + aux[k]})
-            return (x, aux_in), tuple(new_caches) if collect_kv else None
+                losses, st = _split_aux(aux)
+                for k in losses:
+                    aux_in = dict(aux_in, **{k: aux_in.get(k, 0.0)
+                                             + losses[k]})
+                if st:
+                    new_stats.append(st)
+            return (x, aux_in), (tuple(new_caches) if collect_kv else None,
+                                 tuple(new_stats))
 
         body = jax.checkpoint(group_body) if cfg.remat else group_body
-        (x, aux_total), group_caches = jax.lax.scan(
+        (x, aux_total), (group_caches, group_stats) = jax.lax.scan(
             body, (x, aux_total), params["groups"])
+        stats.extend(group_stats)
 
     tail_caches = []
     n_scanned = len(kinds) - len(params["tail"])
     for i, lp in enumerate(params["tail"]):
-        kind = kinds[n_scanned + i]
-
-        def tail_fn(x_, lp_, _kind=kind):
-            return apply_layer(cfg, _kind, lp_, rules, x_, positions,
-                               collect_kv=collect_kv, backend=backend,
-                               cache_capacity=cache_capacity)
-
-        if cfg.remat:   # cost-parity with the checkpointed scan groups
-            tail_fn = jax.checkpoint(tail_fn)
-        x, cache, aux = tail_fn(x, lp)
+        x, cache, aux = unrolled(x, lp, kinds[n_scanned + i])
         tail_caches.append(cache)
-        for k in aux:
-            aux_total[k] = aux_total.get(k, 0.0) + aux[k]
+        add(aux)
 
     if last_only:
         x = x[:, -1:]
     logits = layers.logits_head(cfg, params["emb"], rules, x)
-    caches = ({"groups": group_caches, "tail": tuple(tail_caches)}
-              if collect_kv else None)
-    return logits, caches, aux_total
+    caches = None
+    if collect_kv:
+        caches = {"groups": group_caches, "tail": tuple(tail_caches)}
+        if "lead" in params:
+            caches["lead"] = tuple(lead_caches)
+    return logits, caches, dict(aux_total, **_gather_stats(stats))
 
 
 def decode_step(cfg: ModelConfig, params, caches, batch, rules, *,
                 backend="auto"):
     """One-token decode. batch: {"token_ids": (B,1) or "embeds",
-    "lengths": (B,)}.  Returns (logits (B,1,V), new caches)."""
+    "lengths": (B,)}.  Returns (logits (B,1,V), new caches, stats): the
+    served MoE layers' statistics (``STATS``), empty without MoE."""
     lengths = batch["lengths"]
     x = layers.embed_tokens(cfg, params["emb"], rules, batch)
     positions = lengths[:, None]                      # (B,1) absolute pos
     kinds = cfg.layer_kinds
     P = len(cfg.block_pattern)
+    stats = []
+
+    def one(x, kind, lp, cache):
+        x, cache, aux = apply_layer(cfg, kind, lp, rules, x, positions,
+                                    cache=cache, lengths=lengths,
+                                    backend=backend)
+        st = _split_aux(aux)[1]
+        if st:
+            stats.append(st)
+        return x, cache
+
+    new_lead = []
+    for i, lp in enumerate(params.get("lead", ())):
+        x, cache = one(x, kinds[i], lp, caches["lead"][i])
+        new_lead.append(cache)
 
     new_group_caches = None
     if params["groups"]:
         def group_body(x, scanned):
             group_params, group_cache = scanned
-            new_caches = []
+            new_caches, new_stats = [], []
             for pos in range(P):
                 kind = cfg.block_pattern[pos]
-                x, cache, _ = apply_layer(
+                x, cache, aux = apply_layer(
                     cfg, kind, group_params[pos], rules, x, positions,
                     cache=group_cache[pos], lengths=lengths, backend=backend)
                 new_caches.append(cache)
-            return x, tuple(new_caches)
+                st = _split_aux(aux)[1]
+                if st:
+                    new_stats.append(st)
+            return x, (tuple(new_caches), tuple(new_stats))
 
-        x, new_group_caches = jax.lax.scan(
+        x, (new_group_caches, group_stats) = jax.lax.scan(
             group_body, x, (params["groups"], caches["groups"]))
+        stats.extend(group_stats)
 
     new_tail = []
     n_scanned = len(kinds) - len(params["tail"])
     for i, lp in enumerate(params["tail"]):
-        kind = kinds[n_scanned + i]
-        x, cache, _ = apply_layer(cfg, kind, lp, rules, x, positions,
-                                  cache=caches["tail"][i], lengths=lengths,
-                                  backend=backend)
+        x, cache = one(x, kinds[n_scanned + i], lp, caches["tail"][i])
         new_tail.append(cache)
 
     logits = layers.logits_head(cfg, params["emb"], rules, x)
-    return logits, {"groups": new_group_caches, "tail": tuple(new_tail)}
+    new = {"groups": new_group_caches, "tail": tuple(new_tail)}
+    if "lead" in params:
+        new["lead"] = tuple(new_lead)
+    return logits, new, _gather_stats(stats)
 
 
 def loss_fn(cfg: ModelConfig, params, batch, rules, *, backend="auto"):

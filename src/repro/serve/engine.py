@@ -21,6 +21,11 @@ splice were dispatched, when its first token reached the host, and the
 bytes its splice wrote.  The engine stamps it on the wall clock the
 ``repro.obs`` tracer uses, tracer or not, and its ``engine.*`` spans
 reuse the same stamps.
+
+For a model with MoE layers the prefill and the decode step also return
+the rows each held expert computed in each layer; the engine pulls them
+to the host with the tokens it samples (no sync of their own) and sums
+them into :class:`EngineMetrics`.
 """
 from __future__ import annotations
 
@@ -113,6 +118,14 @@ class EngineMetrics:
     #: wall-clock ms of the most recent decode step (prefills excluded).
     last_step_ms: float = 0.0
     decode_ms_total: float = 0.0
+    #: rows the held experts computed, over MoE layers, prefills and steps
+    moe_rows_total: int = 0
+    #: held experts times MoE layers, summed over prefills and decode steps
+    moe_expert_steps: int = 0
+    #: of those, the ones given at least one row (each read its weights)
+    moe_experts_touched: int = 0
+    #: expert assignments left uncomputed; the serving path drops none
+    moe_dropped_rows: int = 0
 
     @property
     def mean_step_ms(self) -> float:
@@ -204,7 +217,7 @@ class ServingEngine:
                 with tracer.span("engine.prefill", "serve",
                                  t0_ms=tm.admitted) as sp:
                     batch = {"token_ids": jnp.asarray(req.prompt)[None]}
-                    logits, cache1 = self._prefill(self.params, batch)
+                    logits, cache1, stats = self._prefill(self.params, batch)
                     if self.on_logits is not None:
                         self.on_logits("prefill", logits)
                     sp.t1 = tm.prefilled = _now_ms()
@@ -214,8 +227,11 @@ class ServingEngine:
                     sp.t1 = tm.dispatched = _now_ms()
                 with tracer.span("engine.first_token", "serve",
                                  t0_ms=tm.dispatched) as sp:
-                    tok = int(jnp.argmax(logits[0, -1]))
+                    tok, stats = jax.device_get(
+                        (jnp.argmax(logits[0, -1]), stats))
                     sp.t1 = admit_sp.t1 = tm.first_token = _now_ms()
+            self._count_moe(stats)
+            tok = int(tok)
             req.tokens.append(tok)
             self.slots[slot] = req
             self.lengths[slot] = len(req.prompt)
@@ -223,23 +239,36 @@ class ServingEngine:
             self.counters.admitted += 1
             self.counters.tokens_out += 1
 
+    def _count_moe(self, stats: dict):
+        """Add a prefill's or a step's MoE statistics (host arrays)."""
+        if not stats:
+            return
+        rows = np.asarray(stats["moe_rows"])          # (MoE layers, held)
+        c = self.counters
+        c.moe_rows_total += int(rows.sum())
+        c.moe_expert_steps += rows.size
+        c.moe_experts_touched += int((rows > 0).sum())
+        c.moe_dropped_rows += int(stats["moe_dropped"])
+
     def _splice(self, slot: int, cache1) -> int:
         """Write a single-request cache into ``slot`` of the batched cache;
         returns the bytes written.  Group caches are stacked
-        (n_groups, batch, ...), tail caches are (batch, ...).  The eager
-        ``.at[].set`` is out of place, so every replaced leaf is written
-        whole."""
+        (n_groups, batch, ...), every other part ("lead", "tail") is
+        (batch, ...).  The eager ``.at[].set`` is out of place, so every
+        replaced leaf is written whole."""
         new = dict(self.caches)
         replaced = []
-        if self.caches["groups"] is not None:
-            new["groups"] = jax.tree.map(
-                lambda big, one: big.at[:, slot].set(one[:, 0]),
-                self.caches["groups"], cache1["groups"])
-            replaced += jax.tree.leaves(self.caches["groups"])
-        new["tail"] = jax.tree.map(
-            lambda big, one: big.at[slot].set(one[0]),
-            self.caches["tail"], cache1["tail"])
-        replaced += jax.tree.leaves(self.caches["tail"])
+        for part, big in self.caches.items():
+            if big is None:
+                continue
+            if part == "groups":
+                new[part] = jax.tree.map(
+                    lambda b, one: b.at[:, slot].set(one[:, 0]),
+                    big, cache1[part])
+            else:
+                new[part] = jax.tree.map(
+                    lambda b, one: b.at[slot].set(one[0]), big, cache1[part])
+            replaced += jax.tree.leaves(big)
         self.caches = new
         return sum(leaf.nbytes for leaf in replaced)
 
@@ -263,12 +292,15 @@ class ServingEngine:
         with tracer.span("engine.decode", "serve", t0_ms=t0) as sp:
             batch = {"token_ids": jnp.asarray(self.last_tok)[:, None],
                      "lengths": jnp.asarray(self.lengths)}
-            logits, self.caches = self._decode(self.params, self.caches,
-                                               batch)
+            logits, self.caches, stats = self._decode(
+                self.params, self.caches, batch)
             if self.on_logits is not None:
                 self.on_logits("decode", logits)
-            toks = np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
+            toks, stats = jax.device_get(
+                (jnp.argmax(logits[:, 0], axis=-1), stats))
+            toks = np.asarray(toks, np.int32)
             sp.t1 = t1 = _now_ms()
+        self._count_moe(stats)
         self.counters.last_step_ms = t1 - t0
         self.counters.decode_ms_total += self.counters.last_step_ms
         self.counters.steps += 1

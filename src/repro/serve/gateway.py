@@ -58,10 +58,16 @@ _DTYPE_BYTES = {"int8": 1, "float16": 2, "bfloat16": 2, "float32": 4}
 
 
 def kv_bytes_per_token(cfg: ModelConfig) -> int:
-    """KV-cache bytes one decoded token pins in shared memory."""
-    n_attn = sum(1 for k in cfg.layer_kinds if k in ("attn", "local"))
-    return (2 * cfg.n_kv_heads * cfg.d_head
-            * _DTYPE_BYTES.get(cfg.kv_cache_dtype, 2) * n_attn)
+    """KV-cache bytes one decoded token pins in shared memory: keys and
+    values per head in attention layers, one latent row in ``mla``
+    layers."""
+    kinds = cfg.layer_kinds
+    per_elem = _DTYPE_BYTES.get(cfg.kv_cache_dtype, 2)
+    n_attn = sum(1 for k in kinds if k in ("attn", "local"))
+    n_mla = sum(1 for k in kinds if k == "mla")
+    latent = cfg.mla.cache_width if n_mla else 0
+    return (2 * cfg.n_kv_heads * cfg.d_head * n_attn
+            + latent * n_mla) * per_elem
 
 
 @dataclass(frozen=True)

@@ -63,6 +63,7 @@ def test_full_config_matches_assignment(arch):
         "hubert-xlarge": (48, 1280, 16, 16, 5120, 504),
         "dbrx-132b": (40, 6144, 48, 8, 10752, 100352),
         "qwen3-moe-235b-a22b": (94, 4096, 64, 4, 1536, 151936),
+        "deepseek-v2-lite": (27, 2048, 16, 16, 10944, 102400),
     }[arch]
     got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
            cfg.d_ff, cfg.vocab)
@@ -71,6 +72,11 @@ def test_full_config_matches_assignment(arch):
         assert (cfg.moe.n_experts, cfg.moe.top_k) == (16, 4)
     if arch == "qwen3-moe-235b-a22b":
         assert (cfg.moe.n_experts, cfg.moe.top_k) == (128, 8)
+    if arch == "deepseek-v2-lite":
+        assert (cfg.moe.n_experts, cfg.moe.top_k, cfg.moe.d_ff,
+                cfg.moe.n_shared, cfg.first_dense) == (64, 6, 1408, 2, 1)
+        assert (cfg.mla.kv_rank, cfg.mla.q_dim, cfg.mla.v_dim) == (512, 192,
+                                                                   128)
 
 
 @pytest.mark.parametrize("arch", ARCHS)
@@ -118,14 +124,14 @@ def test_prefill_decode_consistency(built, arch):
     want = logits_full[:, S]
 
     # prefill [0, S) then decode token S
-    last_logits, caches = m.prefill(params, prefix)
+    last_logits, caches, _ = m.prefill(params, prefix)
     # prefill's last logits equal forward position S-1
     np.testing.assert_allclose(np.asarray(last_logits[:, 0]),
                                np.asarray(logits_full[:, S - 1]),
                                atol=2e-3, rtol=2e-3)
     step = {"token_ids": full["token_ids"][:, S:S + 1],
             "lengths": jnp.full((B,), S, jnp.int32)}
-    got, _ = m.decode_step(params, caches, step)
+    got, _, _ = m.decode_step(params, caches, step)
     np.testing.assert_allclose(np.asarray(got[:, 0]), np.asarray(want),
                                atol=2e-3, rtol=2e-3)
 
@@ -142,11 +148,11 @@ def test_multi_step_decode_consistency(built, arch):
 
     prefix = {k: (v[:, :S] if k == "token_ids" else v)
               for k, v in ref_in.items()}
-    _, caches = m.prefill(params, prefix)
+    _, caches, _ = m.prefill(params, prefix)
     for t in range(N):
         step = {"token_ids": full["token_ids"][:, S + t:S + t + 1],
                 "lengths": jnp.full((B,), S + t, jnp.int32)}
-        got, caches = m.decode_step(params, caches, step)
+        got, caches, _ = m.decode_step(params, caches, step)
         np.testing.assert_allclose(
             np.asarray(got[:, 0]), np.asarray(logits_full[:, S + t]),
             atol=3e-3, rtol=3e-3, err_msg=f"{arch} step {t}")
@@ -178,10 +184,10 @@ def test_int8_kv_cache_decode_close(built):
     prefix = {"token_ids": full["token_ids"][:, :S]}
     step = {"token_ids": full["token_ids"][:, S:S + 1],
             "lengths": jnp.full((B,), S, jnp.int32)}
-    _, c32 = m.prefill(params, prefix)
-    got32, _ = m.decode_step(params, c32, step)
-    _, c8 = m8.prefill(params, prefix)
-    got8, _ = m8.decode_step(params, c8, step)
+    _, c32, _ = m.prefill(params, prefix)
+    got32, _, _ = m.decode_step(params, c32, step)
+    _, c8, _ = m8.prefill(params, prefix)
+    got8, _, _ = m8.decode_step(params, c8, step)
     # int8 absmax quantization: ~1% relative error on logits
     np.testing.assert_allclose(np.asarray(got8), np.asarray(got32),
                                atol=0.15, rtol=0.1)

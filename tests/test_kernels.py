@@ -307,3 +307,61 @@ class TestSlowdownAutoDispatch:
                                           m.ext_knots, m.table,
                                           backend="auto")
         assert out.shape == own.shape
+
+
+class TestMLADecode:
+    """Absorbed latent attention over (B, S, W) latent rows."""
+
+    @pytest.mark.parametrize("shape", [(2, 128, 4, 64, 16), (3, 96, 16, 576, 64),
+                                       (1, 64, 8, 160, 32)])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
+    def test_vs_oracle(self, shape, dtype, backend):
+        B, S, H, W, rope = shape
+        q = rand(KEYS[0], (B, H, W), dtype)
+        cache = rand(KEYS[1], (B, S, W), dtype)
+        lengths = jnp.array([S // 2 + 7 * i + 1 for i in range(B)],
+                            jnp.int32) % S + 1
+        got = ops.mla_decode(q * W ** -0.5, cache, lengths,
+                             value_width=W - rope, backend=backend)
+        want = ref.mla_decode(q * W ** -0.5, cache, lengths, W - rope)
+        np.testing.assert_allclose(
+            got.astype(jnp.float32), want.astype(jnp.float32), **tol(dtype))
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_kv_tiles_past_length_are_skipped(self, dtype):
+        """Several kv tiles per sequence; lengths at and across tile
+        edges."""
+        from repro.kernels.mla_decode import mla_decode
+        B, S, H, W = 4, 128, 8, 96
+        q = rand(KEYS[2], (B, H, W), dtype) * W ** -0.5
+        cache = rand(KEYS[3], (B, S, W), dtype)
+        lengths = jnp.array([1, 31, 32, 128], jnp.int32)
+        got = mla_decode(q, cache, lengths, value_width=64, block_kv=32,
+                         interpret=True)
+        want = ref.mla_decode(q, cache, lengths, 64)
+        np.testing.assert_allclose(
+            got.astype(jnp.float32), want.astype(jnp.float32), **tol(dtype))
+
+
+class TestMoEGmm:
+    """Grouped SwiGLU over rows sorted by expert; rows past the groups
+    come back as zeros."""
+
+    @pytest.mark.parametrize("sizes", [(3, 0, 5, 2), (0, 0, 0, 9), (1, 1, 1, 1),
+                                       (40, 30, 0, 50)])
+    @pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
+    def test_vs_oracle(self, sizes, backend):
+        E, d, ff = 4, 128, 96
+        m = 24 if sum(sizes) < 24 else 136
+        x = rand(KEYS[0], (m, d), jnp.float32)
+        wg = rand(KEYS[1], (E, d, ff), jnp.float32) * d ** -0.5
+        wi = rand(KEYS[2], (E, d, ff), jnp.float32) * d ** -0.5
+        wo = rand(KEYS[3], (E, ff, d), jnp.float32) * ff ** -0.5
+        gs = jnp.array(sizes, jnp.int32)
+        got = ops.moe_gmm(x, wg, wi, wo, gs, backend=backend)
+        want = ref.moe_gmm(x, wg, wi, wo, gs)
+        assert got.shape == (m, d)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-4, rtol=1e-4)
+        assert not np.asarray(got[sum(sizes):]).any()

@@ -143,6 +143,15 @@ class TestGatewayServing:
         assert kv_bytes_per_token(STABLE) == (
             2 * STABLE.n_kv_heads * STABLE.d_head * 4 * n_attn)  # float32
 
+    def test_kv_bytes_per_token_counts_the_latent_cache(self):
+        """An ``mla`` layer pins one latent row per token, as stored: 576
+        lanes padded to 640, bfloat16, over DeepSeek-V2-Lite's 27 layers."""
+        dsv2 = configs.get("deepseek-v2-lite")
+        assert kv_bytes_per_token(dsv2) == 640 * 2 * 27
+        small = dsv2.reduced()
+        assert kv_bytes_per_token(small) == (
+            small.mla.cache_width * 4 * small.n_layers)       # float32
+
 
 # ---------------------------------------------------------------------------
 # dynamic loop

@@ -18,8 +18,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import decode_attention, flash_attention, rglru, rwkv6
-from repro.kernels import search, slowdown
+from repro.kernels import decode_attention, flash_attention, mla_decode
+from repro.kernels import moe_gmm, rglru, rwkv6, search, slowdown
 from repro.profiling import probes
 
 #: stablelm-1.6b attention widths: 32 heads (MHA) of 64 dims
@@ -88,6 +88,26 @@ CASES = {
          _sds((4, 2048, 8 * 128), jnp.bfloat16),
          _sds((4, 2048, 8 * 128), jnp.bfloat16),
          _sds((4,), jnp.int32))),
+    "flash_attention_mla_prompt512": (
+        lambda q, k, v: flash_attention.flash_attention(q, k, v),
+        (_sds((1, 512, 16, 192), jnp.bfloat16),) * 2
+        + (_sds((1, 512, 16, 128), jnp.bfloat16),)),
+    "mla_decode_12slots_cap1024": (
+        lambda q, c, n: mla_decode.mla_decode(q, c, n, value_width=512),
+        (_sds((12, 16, 576), jnp.bfloat16),
+         _sds((12, 1024, 576), jnp.bfloat16),
+         _sds((12,), jnp.int32))),
+    "moe_gmm_decode_rows": (
+        lambda x, g, i, o, n: moe_gmm.moe_gmm(x, g, i, o, n),
+        (_sds((72, 2048), jnp.bfloat16), _sds((8, 2048, 1408), jnp.bfloat16),
+         _sds((8, 2048, 1408), jnp.bfloat16),
+         _sds((8, 1408, 2048), jnp.bfloat16), _sds((8,), jnp.int32))),
+    "moe_gmm_prefill_rows": (
+        lambda x, g, i, o, n: moe_gmm.moe_gmm(x, g, i, o, n),
+        (_sds((3072, 2048), jnp.bfloat16),
+         _sds((8, 2048, 1408), jnp.bfloat16),
+         _sds((8, 2048, 1408), jnp.bfloat16),
+         _sds((8, 1408, 2048), jnp.bfloat16), _sds((8,), jnp.int32))),
     "rglru_scan_prefill": (
         lambda a, b: rglru.rglru_scan(a, b),
         (_sds((2, 300, 2560), jnp.bfloat16),) * 2),
@@ -154,3 +174,38 @@ def test_decode_step_keeps_the_cache_layout(one_chip):
                                            ).compile().as_text()
     assert "tpu_custom_call" in hlo
     assert _copies_of_length(hlo, capacity) == []
+
+
+def test_mla_decode_step_compiles_with_its_kernels(one_chip):
+    """deepseek-v2-lite at published widths (the dense layer and one MoE
+    layer, 8 of 64 experts held, vocab 1,024, 2 slots of 384): the
+    compiled decode step runs the latent-attention and expert kernels
+    under their names and re-lays out no cache.  (The leading layer's
+    cache is copied whole, in its own layout: the step does not donate
+    it.)"""
+    import dataclasses
+
+    from repro import configs
+    from repro.models import build
+
+    slots, capacity = 2, 384
+    base = configs.get("deepseek-v2-lite")
+    cfg = dataclasses.replace(base, n_layers=2, vocab=1024, moe=dataclasses
+                              .replace(base.moe, n_held=8))
+    model = build(cfg, backend="pallas")
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = place(model.abstract_params())
+    caches = place(jax.eval_shape(lambda: model.init_cache(slots, capacity)))
+    batch = place({"token_ids": _sds((slots, 1), jnp.int32),
+                   "lengths": _sds((slots,), jnp.int32)})
+    hlo = jax.jit(model.decode_step).lower(params, caches, batch
+                                           ).compile().as_text()
+    names = re.findall(r"%(\w+)\.\d+ = \S+ custom-call\(", hlo)
+    assert "mla_decode" in names and "moe_gmm" in names
+    relaid = [c for c in _copies_of_length(hlo, capacity)
+              if not re.search(r"\{2,1,0[:}]", c)]
+    assert relaid == []
