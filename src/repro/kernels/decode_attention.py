@@ -9,16 +9,19 @@ the lengths arrive by scalar prefetch, and the kv index map clamps to the
 last live tile, so a skipped step re-uses the block already in VMEM.
 
 The cache arrives as it is stored (``models/kvcache.py``), lane-dense
-``(B, S, Hkv·D)`` with heads major in the last dim: a block is
-``(1, kv_tile, Hkv·D)``, every head of a tile of positions, with
-lane-dense last dims as Mosaic requires.  A ``(B, S, Hkv, D)`` cache
-would need a reshape here, and on a TPU that is no free view: with D = 64
-XLA stores such a cache sequence-minor and re-lays it out whole.  Per-head
-dot products become one segmented lane reduction — ``(k ⊙ q) @ E`` with the
-0/1 head-indicator matrix ``E`` (Hkv·D, Hkv) on the MXU — and the softmax
-weights are broadcast back onto their head's lanes by ``p @ Eᵀ``.  For GQA
-the query heads are regrouped to ``(group, Hkv·D)`` rows, one per member
-of each kv head's query group.
+``(B, S, Hkv·D)`` with heads major in the last dim, inside the stack of
+layers ``(L, B, S, Hkv·D)`` the decode scan carries: the layer index is
+a second scalar-prefetch operand, so the kernel reads its layer's tiles
+where they lie and no layer is sliced out of the stack first.  A block
+is ``(1, kv_tile, Hkv·D)`` (the layer dim squeezed), every head of a
+tile of positions, with lane-dense last dims as Mosaic requires.  A
+``(B, S, Hkv, D)`` cache would need a reshape here, and on a TPU that is
+no free view: with D = 64 XLA stores such a cache sequence-minor and
+re-lays it out whole.  Per-head dot products become one segmented lane
+reduction — ``(k ⊙ q) @ E`` with the 0/1 head-indicator matrix ``E``
+(Hkv·D, Hkv) on the MXU — and the softmax weights are broadcast back onto
+their head's lanes by ``p @ Eᵀ``.  For GQA the query heads are regrouped
+to ``(group, Hkv·D)`` rows, one per member of each kv head's query group.
 """
 from __future__ import annotations
 
@@ -41,8 +44,9 @@ def _dot(a, b, contract):
                                preferred_element_type=jnp.float32)
 
 
-def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            scale: float, block_kv: int, head_dim: int, group: int):
+def _kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
+            acc_scr, *, scale: float, block_kv: int, head_dim: int,
+            group: int):
     b = pl.program_id(0)
     ikv = pl.program_id(1)
     n_kv = pl.num_programs(1)
@@ -87,12 +91,13 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 @functools.partial(jax.jit, static_argnames=("block_kv", "interpret"))
-def decode_attention(q, k_cache, v_cache, lengths, *, block_kv: int = 512,
-                     interpret: bool = False):
-    """q: (B, 1, Hq, D); caches: (B, S, Hkv·D); lengths: (B,) int32."""
+def decode_attention(q, k_cache, v_cache, lengths, layer, *,
+                     block_kv: int = 512, interpret: bool = False):
+    """q: (B, 1, Hq, D); caches: stacked layers (L, B, S, Hkv·D), of which
+    layer ``layer`` (an int32 scalar) is read; lengths: (B,) int32."""
     b, sq, hq, d = q.shape
     assert sq == 1, "decode kernel: one query token"
-    _, skv, hd = v_cache.shape
+    _, _, skv, hd = v_cache.shape
     assert hd % d == 0, "decode kernel: one head dim for q, k and v"
     hkv = hd // d
     group = hq // hkv
@@ -106,24 +111,25 @@ def decode_attention(q, k_cache, v_cache, lengths, *, block_kv: int = 512,
     qg = q.reshape(b, hkv, group, d).transpose(0, 2, 1, 3).reshape(
         b, group, hd)
 
-    def kv_map(b_, ikv, lens):
+    def kv_map(b_, ikv, lens, lay):
         last = jnp.maximum(lens[b_] - 1, 0) // block_kv
-        return b_, jnp.minimum(ikv, last), 0
+        return lay[0], b_, jnp.minimum(ikv, last), 0
 
     kernel = functools.partial(_kernel, scale=scale, block_kv=block_kv,
                                head_dim=d, group=group)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(b, pl.cdiv(skv, block_kv)),
             in_specs=[
-                pl.BlockSpec((1, group, hd), lambda b_, ikv, lens: (b_, 0, 0)),
-                pl.BlockSpec((1, block_kv, hd), kv_map),
-                pl.BlockSpec((1, block_kv, hd), kv_map),
+                pl.BlockSpec((1, group, hd),
+                             lambda b_, ikv, lens, lay: (b_, 0, 0)),
+                pl.BlockSpec((pl.squeezed, 1, block_kv, hd), kv_map),
+                pl.BlockSpec((pl.squeezed, 1, block_kv, hd), kv_map),
             ],
             out_specs=pl.BlockSpec((1, group, hd),
-                                   lambda b_, ikv, lens: (b_, 0, 0)),
+                                   lambda b_, ikv, lens, lay: (b_, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((group, hkv), jnp.float32),    # running max
                 pltpu.VMEM((group, hkv), jnp.float32),    # denominator
@@ -131,6 +137,7 @@ def decode_attention(q, k_cache, v_cache, lengths, *, block_kv: int = 512,
             ]),
         out_shape=jax.ShapeDtypeStruct((b, group, hd), q.dtype),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), qg, k_cache, v_cache)
+    )(lengths.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+      qg, k_cache, v_cache)
     return out.reshape(b, group, hkv, d).transpose(0, 2, 1, 3).reshape(
         b, 1, hq, d)

@@ -17,8 +17,10 @@ the online-softmax state in VMEM scratch.  Tiles past a sequence's length
 are neither read nor computed: the lengths arrive by scalar prefetch, and
 the kv index map clamps to the last live tile.  The cache arrives as
 stored (``models/kvcache.py``), ``(B, S, W)`` with ``W`` the row's
-``kv_rank + rope_dim`` lanes zero-padded to whole 128-lane tiles, whole
-rows in each block; the query is padded alike.
+``kv_rank + rope_dim`` lanes zero-padded to whole 128-lane tiles, inside
+the stack of layers ``(L, B, S, W)`` the decode scan carries; the layer
+index is a second scalar-prefetch operand, and each block holds whole
+rows of that layer (the layer dim squeezed).  The query is padded alike.
 """
 from __future__ import annotations
 
@@ -32,8 +34,8 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(len_ref, q_ref, c_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            block_kv: int, value_width: int):
+def _kernel(len_ref, layer_ref, q_ref, c_ref, o_ref, m_scr, l_scr, acc_scr,
+            *, block_kv: int, value_width: int):
     b = pl.program_id(0)
     ikv = pl.program_id(1)
     n_kv = pl.num_programs(1)
@@ -75,35 +77,38 @@ def _kernel(len_ref, q_ref, c_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 @functools.partial(jax.jit,
                    static_argnames=("value_width", "block_kv", "interpret"))
-def mla_decode(q, cache, lengths, *, value_width: int, block_kv: int = 512,
-               interpret: bool = False):
-    """q: (B, H, W) absorbed, scaled queries; cache: (B, S, W) latent rows;
-    lengths: (B,) int32 valid positions.  Returns (B, H, value_width): each
-    head's softmax-weighted sum of the rows' first ``value_width`` lanes."""
+def mla_decode(q, cache, lengths, layer, *, value_width: int,
+               block_kv: int = 512, interpret: bool = False):
+    """q: (B, H, W) absorbed, scaled queries; cache: stacked layers of
+    latent rows (L, B, S, W), of which layer ``layer`` (an int32 scalar)
+    is read; lengths: (B,) int32 valid positions.  Returns (B, H,
+    value_width): each head's softmax-weighted sum of the rows' first
+    ``value_width`` lanes."""
     b, h, w = q.shape
-    _, skv, wc = cache.shape
+    _, _, skv, wc = cache.shape
     assert wc == w, "mla decode: the query is as wide as a cached row"
     block_kv = min(block_kv, skv)
     if block_kv < skv:
         block_kv = max(8, block_kv // 8 * 8)
 
-    def kv_map(b_, ikv, lens):
+    def kv_map(b_, ikv, lens, lay):
         last = jnp.maximum(lens[b_] - 1, 0) // block_kv
-        return b_, jnp.minimum(ikv, last), 0
+        return lay[0], b_, jnp.minimum(ikv, last), 0
 
     kernel = functools.partial(_kernel, block_kv=block_kv,
                                value_width=value_width)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(b, pl.cdiv(skv, block_kv)),
             in_specs=[
-                pl.BlockSpec((1, h, w), lambda b_, ikv, lens: (b_, 0, 0)),
-                pl.BlockSpec((1, block_kv, w), kv_map),
+                pl.BlockSpec((1, h, w),
+                             lambda b_, ikv, lens, lay: (b_, 0, 0)),
+                pl.BlockSpec((pl.squeezed, 1, block_kv, w), kv_map),
             ],
             out_specs=pl.BlockSpec((1, h, value_width),
-                                   lambda b_, ikv, lens: (b_, 0, 0)),
+                                   lambda b_, ikv, lens, lay: (b_, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((h, 1), jnp.float32),              # running max
                 pltpu.VMEM((h, 1), jnp.float32),              # denominator
@@ -111,4 +116,5 @@ def mla_decode(q, cache, lengths, *, value_width: int, block_kv: int = 512,
             ]),
         out_shape=jax.ShapeDtypeStruct((b, h, value_width), cache.dtype),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), q.astype(cache.dtype), cache)
+    )(lengths.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+      q.astype(cache.dtype), cache)

@@ -145,15 +145,20 @@ def _attention_xla(q, k, v, *, causal, window, block_kv):
 # ---------------------------------------------------------------------------
 # decode attention (one token, KV cache, per-sequence lengths)
 # ---------------------------------------------------------------------------
-def decode_attention(q, k_cache, v_cache, lengths, *,
+def decode_attention(q, k_cache, v_cache, lengths, *, layer=None,
                      backend: Backend = "auto"):
     """One query token over lane-dense caches. q: (B, 1, Hq, D);
     k_cache, v_cache: (B, S, Hkv·D), heads major in the last dim, as
-    ``models/kvcache.py`` stores them; lengths: (B,) valid positions."""
+    ``models/kvcache.py`` stores them, or with ``layer`` the stacked
+    layers (L, B, S, Hkv·D) and the index of the one to read; lengths:
+    (B,) valid positions."""
+    if layer is None:
+        k_cache, v_cache, layer = k_cache[None], v_cache[None], 0
     b = resolve("decode_attention", backend)
     if b in ("pallas", "pallas_interpret"):
-        return _dec.decode_attention(q, k_cache, v_cache, lengths,
+        return _dec.decode_attention(q, k_cache, v_cache, lengths, layer,
                                      interpret=(b == "pallas_interpret"))
+    k_cache, v_cache = k_cache[layer], v_cache[layer]
     d = q.shape[-1]
     k_cache = k_cache.reshape(*k_cache.shape[:2], -1, d)
     v_cache = v_cache.reshape(*v_cache.shape[:2], -1, d)
@@ -186,16 +191,22 @@ def _decode_xla(q, k_cache, v_cache, lengths):
 # ---------------------------------------------------------------------------
 # latent-attention decode (one token, latent cache, per-sequence lengths)
 # ---------------------------------------------------------------------------
-def mla_decode(q, cache, lengths, *, value_width: int,
+def mla_decode(q, cache, lengths, *, value_width: int, layer=None,
                backend: Backend = "auto"):
     """Absorbed latent attention for one query token. q: (B, H, W) scaled
     queries ``[q_n W_uk^T | q_r]``; cache: (B, S, W) rows ``[c | k_r]`` as
-    ``models/kvcache.py`` stores them; lengths: (B,) valid positions.
-    Returns (B, H, value_width), each head's weighted sum of latents."""
+    ``models/kvcache.py`` stores them, or with ``layer`` the stacked
+    layers (L, B, S, W) and the index of the one to read; lengths: (B,)
+    valid positions.  Returns (B, H, value_width), each head's weighted
+    sum of latents."""
+    if layer is None:
+        cache, layer = cache[None], 0
     b = resolve("mla_decode", backend)
     if b in ("pallas", "pallas_interpret"):
-        return _mla.mla_decode(q, cache, lengths, value_width=value_width,
+        return _mla.mla_decode(q, cache, lengths, layer,
+                               value_width=value_width,
                                interpret=(b == "pallas_interpret"))
+    cache = cache[layer]
     if b == "stub":
         kv = cache.sum(1)[:, None, :value_width]             # reads the cache
         scale = (1 + lengths.astype(q.dtype) * 0)[:, None, None]

@@ -31,7 +31,8 @@ def init_layer(batch: int, seq: int, n_kv: int, d: int, dtype: str):
 
 
 def size(layer) -> int:
-    return layer["data"].shape[1]
+    """Positions a layer holds; a stacked leaf ``(L, B, S, ·)`` too."""
+    return layer["data"].shape[-2]
 
 
 def _quant(x):
@@ -57,17 +58,38 @@ def dequant(layer):
     return layer["data"]
 
 
-def insert(layer, new, lengths, window: int | None = None):
-    """Insert one token's kv. new: (B, Hkv, D); lengths: (B,) tokens cached."""
+def insert(layer, new, lengths, window: int | None = None, index=None):
+    """Insert one token's kv. new: (B, Hkv, D); lengths: (B,) tokens cached.
+
+    With ``index`` the view is a stacked leaf ``(L, B, S, ·)`` and the row
+    goes into its layer ``index``: one row per sequence is written, the
+    rest of the stack is left as it is (in place where the caller donates
+    or carries it)."""
     b = new.shape[0]
     slot = lengths % size(layer) if window is not None else lengths
-    rows = jnp.arange(b)
+    at = (jnp.arange(b), slot)
+    if index is not None:
+        at = (index,) + at
     if "scale" in layer:
         q, s = _quant(new)
-        return {"data": layer["data"].at[rows, slot].set(q),
-                "scale": layer["scale"].at[rows, slot].set(s)}
-    return {"data": layer["data"].at[rows, slot].set(
+        return {"data": layer["data"].at[at].set(q),
+                "scale": layer["scale"].at[at].set(s)}
+    return {"data": layer["data"].at[at].set(
         _rows(new, layer["data"].dtype))}
+
+
+def kernel_view(layer, index=None):
+    """What a decode kernel reads: (values, the index of the layer in
+    them, or ``None`` for an unstacked layer).
+
+    A stored leaf goes as it is, so the kernel reads a stacked layer
+    where it lies.  An int8 layer is dequantised, which materialises the
+    one layer (taken from its stack first)."""
+    if "scale" not in layer:
+        return layer["data"], index
+    if index is not None:
+        layer = jax.tree.map(lambda a: a[index], layer)
+    return dequant(layer), None
 
 
 def _build(x, capacity: int, dtype: str, window: int | None):

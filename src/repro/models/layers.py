@@ -131,16 +131,19 @@ def init_attention(cfg: ModelConfig, key):
 
 
 def attention_block(cfg: ModelConfig, p, rules, x, positions, *,
-                    kind: str, cache=None, lengths=None, backend="auto"):
+                    kind: str, cache=None, lengths=None, layer=None,
+                    backend="auto"):
     """Pre-norm attention residual block.
 
     Train/prefill: ``cache is None`` — self-attention over x; returns
     (y, (k, v)) so prefill can build the cache.
     Decode: ``cache = (k_cache, v_cache)``, :mod:`~repro.models.kvcache`
-    layer views stored lane-dense as ``(B, S, Hkv·D)``, and ``lengths``
-    (B,) = tokens already cached; the new token's k/v are inserted at
-    ``lengths`` and attention runs over ``lengths + 1`` on the layers as
-    stored, with no re-layout.
+    layer views stored lane-dense as ``(B, S, Hkv·D)`` — or, with
+    ``layer``, the stacked views ``(L, B, S, Hkv·D)`` of the decode scan
+    and this layer's index in them — and ``lengths`` (B,) = tokens
+    already cached; the new token's k/v are inserted at ``lengths`` and
+    attention runs over ``lengths + 1`` on the layers as stored, with no
+    re-layout and no slice of the layer.
     """
     dt = jnp.dtype(cfg.dtype)
     h = rmsnorm(x, p["ln"]).astype(dt)
@@ -173,16 +176,15 @@ def attention_block(cfg: ModelConfig, p, rules, x, positions, *,
     else:
         from . import kvcache
         kc, vc = cache
-        kc = kvcache.insert(kc, k[:, 0], lengths, window if kind == "local"
-                            else None)
-        vc = kvcache.insert(vc, v[:, 0], lengths, window if kind == "local"
-                            else None)
+        kc = kvcache.insert(kc, k[:, 0], lengths, window, index=layer)
+        vc = kvcache.insert(vc, v[:, 0], lengths, window, index=layer)
         if kind == "local":
             eff_len = jnp.minimum(lengths + 1, kvcache.size(kc))
         else:
             eff_len = lengths + 1
-        out = ops.decode_attention(q, kvcache.dequant(kc),
-                                   kvcache.dequant(vc), eff_len,
+        k_all, at = kvcache.kernel_view(kc, layer)
+        v_all, _ = kvcache.kernel_view(vc, layer)
+        out = ops.decode_attention(q, k_all, v_all, eff_len, layer=at,
                                    backend=backend)
         new_kv = (kc, vc)
     out = sharding.constrain(out, rules, ("batch", "seq", "heads",

@@ -57,13 +57,15 @@ def score_scale(cfg: ModelConfig) -> float:
 
 
 def mla_block(cfg: ModelConfig, p, rules, x, positions, *, cache=None,
-              lengths=None, backend="auto"):
+              lengths=None, layer=None, backend="auto"):
     """Pre-norm latent-attention residual block.
 
     Prefill (``cache is None``): returns ``(y, rows)``, the ``(B, S, W)``
     latent rows a cache is built from.  Decode: ``cache`` is the layer's
-    latent cache view, ``lengths`` (B,) the positions already cached; the
-    new row goes in at ``lengths`` and ``(y, new cache)`` comes back.
+    latent cache view (with ``layer``, the decode scan's stacked views
+    and this layer's index in them), ``lengths`` (B,) the positions
+    already cached; the new row goes in at ``lengths`` and ``(y, new
+    cache)`` comes back.
     """
     m = cfg.mla
     dt = jnp.dtype(cfg.dtype)
@@ -102,12 +104,14 @@ def mla_block(cfg: ModelConfig, p, rules, x, positions, *, cache=None,
                                 block_kv=cfg.attn_block_kv, backend=backend)
             new = rows
         else:
-            new = kvcache.insert(cache, rows[:, 0, None], lengths)
+            new = kvcache.insert(cache, rows[:, 0, None], lengths,
+                                 index=layer)
             q_lat = jnp.einsum("bhk,chk->bhc", q_n[:, 0], wuk)
             q_pad = jnp.zeros((*q_lat.shape[:-1], pad.shape[-1]), dt)
             qa = (jnp.concatenate([q_lat, q_r[:, 0], q_pad], axis=-1)
                   * scale).astype(dt)
-            o_lat = ops.mla_decode(qa, kvcache.dequant(new), lengths + 1,
+            rows_all, at = kvcache.kernel_view(new, layer)
+            o_lat = ops.mla_decode(qa, rows_all, lengths + 1, layer=at,
                                    value_width=m.kv_rank, backend=backend)
             out = jnp.einsum("bhc,chv->bhv", o_lat.astype(dt), wuv)[:, None]
     out = sharding.constrain(out, rules, ("batch", "seq", "heads",

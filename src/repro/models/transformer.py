@@ -62,16 +62,24 @@ def init_layer(cfg: ModelConfig, kind: str, key, dense: bool = False):
 
 
 def apply_layer(cfg: ModelConfig, kind: str, p, rules, x, positions, *,
-                cache=None, lengths=None, collect_kv=False, backend="auto",
-                cache_capacity=None):
+                cache=None, lengths=None, layer=None, collect_kv=False,
+                backend="auto", cache_capacity=None):
+    """One layer.  With ``layer`` the cache leaves are the decode scan's
+    stacks ``(L, ...)`` and this layer is ``layer`` of them: attention
+    kinds write their new row into the stack and read it in place; the
+    recurrent kinds, whose state is small, take their layer out and put
+    it back."""
     aux = {}
     new_cache = None
     served = cache is not None or collect_kv
+    if layer is not None and kind in ("rglru", "rwkv"):
+        stack = cache
+        cache = jax.tree.map(lambda a: a[layer], stack)
     if kind in ("attn", "local"):
         att_cache = None if cache is None else (cache["k"], cache["v"])
         x, kv = layers.attention_block(
             cfg, p["t"], rules, x, positions, kind=kind, cache=att_cache,
-            lengths=lengths, backend=backend)
+            lengths=lengths, layer=layer, backend=backend)
         if cache is not None:
             new_cache = {"k": kv[0], "v": kv[1]}
         elif collect_kv:
@@ -82,7 +90,7 @@ def apply_layer(cfg: ModelConfig, kind: str, p, rules, x, positions, *,
             new_cache = {"k": kc, "v": vc}
     elif kind == "mla":
         x, rows = mla.mla_block(cfg, p["t"], rules, x, positions,
-                                cache=cache, lengths=lengths,
+                                cache=cache, lengths=lengths, layer=layer,
                                 backend=backend)
         if cache is not None:
             new_cache = rows
@@ -97,6 +105,9 @@ def apply_layer(cfg: ModelConfig, kind: str, p, rules, x, positions, *,
         x, st = recurrent.rwkv_block(cfg, p["t"], rules, x,
                                      state=cache, backend=backend)
         new_cache = st if (cache is not None or collect_kv) else None
+    if layer is not None and kind in ("rglru", "rwkv"):
+        new_cache = jax.tree.map(lambda a, n: a.at[layer].set(n), stack,
+                                 new_cache)
     if "c" in p:
         if "router" not in p["c"]:
             x = layers.mlp_block(cfg, p["c"], rules, x)
@@ -306,22 +317,29 @@ def decode_step(cfg: ModelConfig, params, caches, batch, rules, *,
 
     new_group_caches = None
     if params["groups"]:
-        def group_body(x, scanned):
-            group_params, group_cache = scanned
+        # the stacked caches ride in the carry and each layer writes its
+        # one new row into them: scanning them as inputs and outputs would
+        # slice every layer out whole and write it back whole, every step
+        def group_body(carry, scanned):
+            x, group_cache = carry
+            group_params, g = scanned
             new_caches, new_stats = [], []
             for pos in range(P):
                 kind = cfg.block_pattern[pos]
                 x, cache, aux = apply_layer(
                     cfg, kind, group_params[pos], rules, x, positions,
-                    cache=group_cache[pos], lengths=lengths, backend=backend)
+                    cache=group_cache[pos], lengths=lengths, layer=g,
+                    backend=backend)
                 new_caches.append(cache)
                 st = _split_aux(aux)[1]
                 if st:
                     new_stats.append(st)
-            return x, (tuple(new_caches), tuple(new_stats))
+            return (x, tuple(new_caches)), tuple(new_stats)
 
-        x, (new_group_caches, group_stats) = jax.lax.scan(
-            group_body, x, (params["groups"], caches["groups"]))
+        n_groups = jax.tree.leaves(params["groups"])[0].shape[0]
+        (x, new_group_caches), group_stats = jax.lax.scan(
+            group_body, (x, caches["groups"]),
+            (params["groups"], jnp.arange(n_groups)))
         stats.extend(group_stats)
 
     new_tail = []

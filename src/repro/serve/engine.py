@@ -126,6 +126,9 @@ class EngineMetrics:
     moe_experts_touched: int = 0
     #: expert assignments left uncomputed; the serving path drops none
     moe_dropped_rows: int = 0
+    #: decode steps whose input cache was donated to the step, so the
+    #: step updated it in place (read off the old leaves, no host sync)
+    cache_inplace_steps: int = 0
 
     @property
     def mean_step_ms(self) -> float:
@@ -147,7 +150,9 @@ class ServingEngine:
         self.last_tok = np.zeros((max_slots,), np.int32)
         self.caches = model.init_cache(max_slots, capacity)
         self._rid = itertools.count()
-        self._decode = jax.jit(model.decode_step)
+        # the cache is donated: the step writes its new rows into the
+        # stored stacks in place, and one cache is live, not two
+        self._decode = jax.jit(model.decode_step, donate_argnums=1)
         self._prefill = jax.jit(
             lambda p, b: model.prefill(p, b, capacity=capacity))
         self.steps = 0
@@ -292,8 +297,12 @@ class ServingEngine:
         with tracer.span("engine.decode", "serve", t0_ms=t0) as sp:
             batch = {"token_ids": jnp.asarray(self.last_tok)[:, None],
                      "lengths": jnp.asarray(self.lengths)}
+            old = jax.tree.leaves(self.caches)
             logits, self.caches, stats = self._decode(
                 self.params, self.caches, batch)
+            if all(leaf.is_deleted() for leaf in old):
+                self.counters.cache_inplace_steps += 1
+            sp.set(cache_inplace_steps=self.counters.cache_inplace_steps)
             if self.on_logits is not None:
                 self.on_logits("decode", logits)
             toks, stats = jax.device_get(

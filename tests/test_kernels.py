@@ -95,11 +95,39 @@ class TestDecodeAttention:
         k = rand(KEYS[1], (B, S, Hkv, D), dtype)
         v = rand(KEYS[2], (B, S, Hkv, D), dtype)
         lengths = jnp.array([1, 31, 32, 128], jnp.int32)
-        got = decode_attention(q, k.reshape(B, S, -1), v.reshape(B, S, -1),
-                               lengths, block_kv=32, interpret=True)
+        got = decode_attention(q, k.reshape(1, B, S, -1),
+                               v.reshape(1, B, S, -1), lengths, 0,
+                               block_kv=32, interpret=True)
         want = ref.attention(q, k, v, causal=True, lengths=lengths)
         np.testing.assert_allclose(
             got.astype(jnp.float32), want.astype(jnp.float32), **tol(dtype))
+
+    @pytest.mark.parametrize("layer", [0, 1, 2])
+    @pytest.mark.parametrize("backend", ["xla", "pallas_interpret", "ref"])
+    def test_stacked_leaf_reads_its_layer(self, layer, backend):
+        """Over a stacked leaf (L, B, S, Hkv·D) and a layer index, every
+        backend reads that layer, as the oracle does on the layer alone;
+        the kernel takes the index as a traced scalar, several kv tiles."""
+        from repro.kernels import decode_attention as dec
+        L, B, S, Hq, Hkv, D = 3, 2, 96, 4, 2, 32
+        q = rand(KEYS[0], (B, 1, Hq, D), jnp.float32)
+        k = rand(KEYS[1], (L, B, S, Hkv, D), jnp.float32)
+        v = rand(KEYS[2], (L, B, S, Hkv, D), jnp.float32)
+        lengths = jnp.array([40, 96], jnp.int32)
+        index = jnp.asarray(layer, jnp.int32)
+        got = ops.decode_attention(q, k.reshape(L, B, S, -1),
+                                   v.reshape(L, B, S, -1), lengths,
+                                   layer=index, backend=backend)
+        want = ref.attention(q, k[layer], v[layer], causal=True,
+                             lengths=lengths)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   **tol(jnp.float32))
+        if backend == "pallas_interpret":
+            tiled = dec.decode_attention(
+                q, k.reshape(L, B, S, -1), v.reshape(L, B, S, -1), lengths,
+                index, block_kv=32, interpret=True)
+            np.testing.assert_allclose(np.asarray(tiled), np.asarray(want),
+                                       **tol(jnp.float32))
 
 
 class TestLinearScan:
@@ -337,11 +365,34 @@ class TestMLADecode:
         q = rand(KEYS[2], (B, H, W), dtype) * W ** -0.5
         cache = rand(KEYS[3], (B, S, W), dtype)
         lengths = jnp.array([1, 31, 32, 128], jnp.int32)
-        got = mla_decode(q, cache, lengths, value_width=64, block_kv=32,
-                         interpret=True)
+        got = mla_decode(q, cache[None], lengths, 0, value_width=64,
+                         block_kv=32, interpret=True)
         want = ref.mla_decode(q, cache, lengths, 64)
         np.testing.assert_allclose(
             got.astype(jnp.float32), want.astype(jnp.float32), **tol(dtype))
+
+    @pytest.mark.parametrize("layer", [0, 1, 2])
+    @pytest.mark.parametrize("backend", ["xla", "pallas_interpret", "ref"])
+    def test_stacked_leaf_reads_its_layer(self, layer, backend):
+        """Over stacked latent rows (L, B, S, W) and a layer index, every
+        backend reads that layer, as the oracle does on the layer alone;
+        the kernel takes the index as a traced scalar, several kv tiles."""
+        from repro.kernels.mla_decode import mla_decode
+        L, B, S, H, W = 3, 2, 96, 8, 160
+        q = rand(KEYS[2], (B, H, W), jnp.float32) * W ** -0.5
+        cache = rand(KEYS[3], (L, B, S, W), jnp.float32)
+        lengths = jnp.array([40, 96], jnp.int32)
+        index = jnp.asarray(layer, jnp.int32)
+        got = ops.mla_decode(q, cache, lengths, value_width=128, layer=index,
+                             backend=backend)
+        want = ref.mla_decode(q, cache[layer], lengths, 128)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   **tol(jnp.float32))
+        if backend == "pallas_interpret":
+            tiled = mla_decode(q, cache, lengths, index, value_width=128,
+                               block_kv=32, interpret=True)
+            np.testing.assert_allclose(np.asarray(tiled), np.asarray(want),
+                                       **tol(jnp.float32))
 
 
 class TestMoEGmm:

@@ -77,26 +77,28 @@ CASES = {
         lambda q, k, v: flash_attention.flash_attention(q, k, v),
         (_kv(512),) * 3),
     "decode_attention_4slots_cap128": (
-        lambda q, k, v, n: decode_attention.decode_attention(q, k, v, n),
+        lambda q, k, v, n, i: decode_attention.decode_attention(q, k, v, n,
+                                                                i),
         (_sds((4, 1, HEADS, HEAD_DIM), jnp.bfloat16),
-         _sds((4, 128, HEADS * HEAD_DIM), jnp.bfloat16),
-         _sds((4, 128, HEADS * HEAD_DIM), jnp.bfloat16),
-         _sds((4,), jnp.int32))),
+         _sds((3, 4, 128, HEADS * HEAD_DIM), jnp.bfloat16),
+         _sds((3, 4, 128, HEADS * HEAD_DIM), jnp.bfloat16),
+         _sds((4,), jnp.int32), _sds((), jnp.int32))),
     "decode_attention_gqa_cap2048": (
-        lambda q, k, v, n: decode_attention.decode_attention(q, k, v, n),
+        lambda q, k, v, n, i: decode_attention.decode_attention(q, k, v, n,
+                                                                i),
         (_sds((4, 1, 24, 128), jnp.bfloat16),
-         _sds((4, 2048, 8 * 128), jnp.bfloat16),
-         _sds((4, 2048, 8 * 128), jnp.bfloat16),
-         _sds((4,), jnp.int32))),
+         _sds((3, 4, 2048, 8 * 128), jnp.bfloat16),
+         _sds((3, 4, 2048, 8 * 128), jnp.bfloat16),
+         _sds((4,), jnp.int32), _sds((), jnp.int32))),
     "flash_attention_mla_prompt512": (
         lambda q, k, v: flash_attention.flash_attention(q, k, v),
         (_sds((1, 512, 16, 192), jnp.bfloat16),) * 2
         + (_sds((1, 512, 16, 128), jnp.bfloat16),)),
     "mla_decode_12slots_cap1024": (
-        lambda q, c, n: mla_decode.mla_decode(q, c, n, value_width=512),
+        lambda q, c, n, i: mla_decode.mla_decode(q, c, n, i, value_width=512),
         (_sds((12, 16, 576), jnp.bfloat16),
-         _sds((12, 1024, 576), jnp.bfloat16),
-         _sds((12,), jnp.int32))),
+         _sds((3, 12, 1024, 576), jnp.bfloat16),
+         _sds((12,), jnp.int32), _sds((), jnp.int32))),
     "moe_gmm_decode_rows": (
         lambda x, g, i, o, n: moe_gmm.moe_gmm(x, g, i, o, n),
         (_sds((72, 2048), jnp.bfloat16), _sds((8, 2048, 1408), jnp.bfloat16),
@@ -138,30 +140,37 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _copies_of_length(hlo: str, n: int) -> list[str]:
-    """The ``copy`` instructions of ``hlo`` whose result has a dim of ``n``."""
+def _shapes(hlo: str) -> dict[str, list[str]]:
+    """Each instruction's result dims, by name."""
+    return {m.group(1): m.group(2).split(",") for m in re.finditer(
+        r"%([\w.-]+) = \w+\[([\d,]*)\]", hlo)}
+
+
+def _whole_layer_moves(hlo: str, slots: int, capacity: int) -> list[str]:
+    """The ``copy``, ``dynamic-slice`` and ``dynamic-update-slice``
+    instructions of ``hlo`` (fused ones too) that move a whole cache layer
+    or stack: a result (for an update, the update written) with dims of
+    both ``slots`` and ``capacity``.  A one-row insert moves neither."""
+    whole = {str(slots), str(capacity)}
+    shapes = _shapes(hlo)
     out = []
     for line in hlo.splitlines():
-        m = re.search(r"=\s*\w+\[([\d,]*)\]\S*\s+copy\(", line)
-        if m and str(n) in m.group(1).split(","):
+        m = re.search(r"= \w+\[([\d,]*)\]\S*\s+(copy|dynamic-slice|"
+                      r"dynamic-update-slice)\(([^)]*)\)", line)
+        if not m:
+            continue
+        dims = m.group(1).split(",")
+        if m.group(2) == "dynamic-update-slice":
+            update = m.group(3).split(",")[1].strip().lstrip("%")
+            dims = shapes.get(update, dims)
+        if whole <= set(dims):
             out.append(line.strip())
     return out
 
 
-def test_decode_step_keeps_the_cache_layout(one_chip):
-    """stablelm-1.6b at published widths (2 layers, 2 slots of 512): the
-    compiled decode step reads and writes each cache layer as stored, with
-    no whole-layer ``copy`` between the cache, the insert and the kernel."""
-    import dataclasses
-
-    from repro import configs
-    from repro.models import build
-
-    slots, capacity = 2, 512
-    cfg = dataclasses.replace(configs.get("stablelm-1.6b"), n_layers=2,
-                              vocab=1024)
-    model = build(cfg, backend="pallas")
-
+def _decode_step_hlo(one_chip, model, slots, capacity):
+    """The v5e-compiled decode step as ``ServingEngine`` jits it, with
+    the cache donated; and the number of cache leaves."""
     def place(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=one_chip), tree)
@@ -170,19 +179,50 @@ def test_decode_step_keeps_the_cache_layout(one_chip):
     caches = place(jax.eval_shape(lambda: model.init_cache(slots, capacity)))
     batch = place({"token_ids": _sds((slots, 1), jnp.int32),
                    "lengths": _sds((slots,), jnp.int32)})
-    hlo = jax.jit(model.decode_step).lower(params, caches, batch
-                                           ).compile().as_text()
-    assert "tpu_custom_call" in hlo
-    assert _copies_of_length(hlo, capacity) == []
+    hlo = jax.jit(model.decode_step, donate_argnums=1).lower(
+        params, caches, batch).compile().as_text()
+    return hlo, len(jax.tree.leaves(caches))
+
+
+def _assert_cache_updated_in_place(hlo, n_leaves, slots, capacity):
+    """Every cache leaf is an input the output aliases, and no whole
+    cache layer or stack is copied, sliced out or written back."""
+    cache_params = re.findall(r"%caches\S*\.\d+ = \S+ parameter\((\d+)\)",
+                              hlo)
+    assert len(cache_params) == n_leaves
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo).group(1)
+    aliased = set(re.findall(r"\((\d+), \{\}", alias))
+    assert set(cache_params) <= aliased
+    assert _whole_layer_moves(hlo, slots, capacity) == []
+
+
+def test_decode_step_keeps_the_cache_layout(one_chip):
+    """stablelm-1.6b at published widths (3 layers, so the scan runs
+    three groups; 2 slots of 512): the compiled decode step, its cache
+    donated as the engine donates it, writes each new row into the
+    stored stacks in place and the kernel reads its layer there, with no
+    whole-layer ``copy``, slice or write-back."""
+    import dataclasses
+
+    from repro import configs
+    from repro.models import build
+
+    slots, capacity = 2, 512
+    cfg = dataclasses.replace(configs.get("stablelm-1.6b"), n_layers=3,
+                              vocab=1024)
+    model = build(cfg, backend="pallas")
+    hlo, n_leaves = _decode_step_hlo(one_chip, model, slots, capacity)
+    names = re.findall(r"%(\w+)\.\d+ = \S+ custom-call\(", hlo)
+    assert "decode_attention" in names
+    _assert_cache_updated_in_place(hlo, n_leaves, slots, capacity)
 
 
 def test_mla_decode_step_compiles_with_its_kernels(one_chip):
-    """deepseek-v2-lite at published widths (the dense layer and one MoE
-    layer, 8 of 64 experts held, vocab 1,024, 2 slots of 384): the
-    compiled decode step runs the latent-attention and expert kernels
-    under their names and re-lays out no cache.  (The leading layer's
-    cache is copied whole, in its own layout: the step does not donate
-    it.)"""
+    """deepseek-v2-lite at published widths (the dense layer and two MoE
+    layers, so the scan runs two groups; 8 of 64 experts held, vocab
+    1,024, 2 slots of 384): the compiled decode step, its cache donated,
+    runs the latent-attention and expert kernels under their names and
+    updates every latent cache, the leading layer's too, in place."""
     import dataclasses
 
     from repro import configs
@@ -190,22 +230,10 @@ def test_mla_decode_step_compiles_with_its_kernels(one_chip):
 
     slots, capacity = 2, 384
     base = configs.get("deepseek-v2-lite")
-    cfg = dataclasses.replace(base, n_layers=2, vocab=1024, moe=dataclasses
+    cfg = dataclasses.replace(base, n_layers=3, vocab=1024, moe=dataclasses
                               .replace(base.moe, n_held=8))
     model = build(cfg, backend="pallas")
-
-    def place(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one_chip), tree)
-
-    params = place(model.abstract_params())
-    caches = place(jax.eval_shape(lambda: model.init_cache(slots, capacity)))
-    batch = place({"token_ids": _sds((slots, 1), jnp.int32),
-                   "lengths": _sds((slots,), jnp.int32)})
-    hlo = jax.jit(model.decode_step).lower(params, caches, batch
-                                           ).compile().as_text()
+    hlo, n_leaves = _decode_step_hlo(one_chip, model, slots, capacity)
     names = re.findall(r"%(\w+)\.\d+ = \S+ custom-call\(", hlo)
     assert "mla_decode" in names and "moe_gmm" in names
-    relaid = [c for c in _copies_of_length(hlo, capacity)
-              if not re.search(r"\{2,1,0[:}]", c)]
-    assert relaid == []
+    _assert_cache_updated_in_place(hlo, n_leaves, slots, capacity)
