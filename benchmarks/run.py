@@ -31,7 +31,6 @@ SUITES = [
     "fig5_scenario1",
     "fig6_contention",
     "fig7_dynamic",
-    "roofline_table",
     "serve_gateway",
 ]
 

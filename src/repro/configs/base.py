@@ -1,7 +1,7 @@
 """Model/run configuration system.
 
 One :class:`ModelConfig` fully describes an architecture (family, dims,
-block pattern, MoE, modality stubs), its numerics (dtypes, remat, scan) and
+block pattern, MoE, modality stubs), its numerics (dtypes, scan) and
 its sharding rules (logical-axis -> mesh-axis mapping, MaxText style).  Every
 assigned architecture ships a full config and a reduced ``smoke()`` config of
 the same family for CPU tests.
@@ -31,9 +31,6 @@ RULES_TP = {
     "mlp": "model",
     "experts": "model",
     "expert_mlp": None,
-    #: MoE dispatch-buffer capacity dim — sharding it over data keeps the
-    #: (E, C, d) buffers from replicating across the data axis.
-    "expert_capacity": "data",
     "rnn": "model",
     "layers": None,
 }
@@ -58,7 +55,6 @@ RULES_ZERO3 = {
     "mlp": None,
     "experts": None,
     "expert_mlp": None,
-    "expert_capacity": ("data", "model"),
     "rnn": None,
     "layers": None,
 }
@@ -69,9 +65,6 @@ class MoEConfig:
     #: experts the router scores (its output width)
     n_experts: int
     top_k: int
-    capacity_factor: float = 1.25
-    aux_loss_weight: float = 0.01
-    router_z_weight: float = 1e-3
     #: one routed expert's SwiGLU width; 0 -> the model's ``d_ff``
     d_ff: int = 0
     #: shared experts every token passes through, run as one SwiGLU of
@@ -200,7 +193,6 @@ class ModelConfig:
     # ---- numerics & memory ----
     dtype: str = "bfloat16"
     param_dtype: str = "float32"
-    remat: bool = True
     scan_layers: bool = True
     kv_cache_dtype: str = "bfloat16"    # or "int8"
     attn_block_q: int = 1024
@@ -210,13 +202,6 @@ class ModelConfig:
         default_factory=lambda: dict(RULES_FSDP_TP))
     serve_rules: Mapping[str, object] = field(
         default_factory=lambda: dict(RULES_TP))
-    # ---- training ----
-    microbatches: int = 1
-    optimizer: str = "adamw"        # adamw | adafactor
-    learning_rate: float = 3e-4
-    weight_decay: float = 0.1
-    grad_clip: float = 1.0
-    z_loss: float = 1e-4
 
     def __post_init__(self):
         if self.d_head == 0:
@@ -232,6 +217,8 @@ class ModelConfig:
             raise ValueError("mla layers require MLAConfig")
         if self.family == "moe" and self.moe is None:
             raise ValueError("moe family requires MoEConfig")
+        if self.moe is not None and self.act != "swiglu":
+            raise ValueError("MoE experts are SwiGLU")
         if self.first_dense and self.moe is None:
             raise ValueError("leading dense layers precede MoE layers")
 
@@ -322,17 +309,13 @@ class ModelConfig:
             dtype="float32",
             param_dtype="float32",
             kv_cache_dtype="float32",
-            microbatches=1,
             name=self.name + "-smoke",
         )
         if self.moe is not None:
-            # generous capacity so reduced-config decode is drop-free and
-            # prefill+decode consistency is exact
             mo = self.moe
             small["moe"] = MoEConfig(
-                n_experts=4, top_k=2, capacity_factor=8.0,
-                d_ff=64 if mo.d_ff else 0, n_shared=mo.n_shared,
-                norm_topk=mo.norm_topk)
+                n_experts=4, top_k=2, d_ff=64 if mo.d_ff else 0,
+                n_shared=mo.n_shared, norm_topk=mo.norm_topk)
         if self.mla is not None:
             small["mla"] = MLAConfig(kv_rank=32, nope_dim=16, rope_dim=8,
                                      v_dim=16)
@@ -349,11 +332,10 @@ class ShapeCell:
     name: str
     seq_len: int
     global_batch: int
-    kind: str          # train | prefill | decode
+    kind: str          # prefill | decode
 
 
 SHAPES: dict[str, ShapeCell] = {
-    "train_4k": ShapeCell("train_4k", 4096, 256, "train"),
     "prefill_32k": ShapeCell("prefill_32k", 32768, 32, "prefill"),
     "decode_32k": ShapeCell("decode_32k", 32768, 128, "decode"),
     "long_500k": ShapeCell("long_500k", 524288, 1, "decode"),

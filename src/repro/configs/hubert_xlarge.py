@@ -2,7 +2,7 @@
 
 48L d_model=1280 16H (MHA kv=16) d_ff=5120 vocab=504 (k-means units)
 [arXiv:2106.07447].  The CNN feature extractor is a STUB per the
-assignment: input_specs() provides precomputed frame embeddings.
+assignment: the batch carries precomputed frame embeddings.
 Encoder-only -> no decode shapes.
 """
 from .base import ModelConfig, RULES_ZERO3
@@ -19,6 +19,5 @@ CONFIG = ModelConfig(
     bidirectional=True,
     embeds_only=True,
     act="gelu",
-    microbatches=1,
     rules=dict(RULES_ZERO3),
 )

@@ -1,7 +1,7 @@
 """internvl2-2b [vlm]: InternViT frontend (stubbed) + InternLM2-1.8b backbone.
 
 24L d_model=2048 16H (GQA kv=8) d_ff=8192 vocab=92553 [arXiv:2404.16821].
-The vision frontend is a STUB per the assignment: input_specs() provides
+The vision frontend is a STUB per the assignment: the batch carries
 precomputed patch embeddings (1024-dim InternViT features) that a learned
 projector maps into the first mm_prefix positions.
 """
@@ -19,6 +19,5 @@ CONFIG = ModelConfig(
     mm_prefix=1024,
     mm_embed_dim=1024,
     act="swiglu",
-    microbatches=1,
     rules=dict(RULES_ZERO3),
 )

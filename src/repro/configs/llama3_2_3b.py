@@ -14,6 +14,5 @@ CONFIG = ModelConfig(
     rope_theta=500000.0,
     tie_embeddings=True,
     act="swiglu",
-    microbatches=1,
     rules=dict(RULES_ZERO3),
 )

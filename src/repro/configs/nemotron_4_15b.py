@@ -14,6 +14,5 @@ CONFIG = ModelConfig(
     d_ff=24576,
     vocab=256000,
     act="squared_relu",
-    microbatches=1,
     rules=dict(RULES_ZERO3),
 )

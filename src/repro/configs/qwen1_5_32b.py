@@ -19,6 +19,5 @@ CONFIG = ModelConfig(
     qkv_bias=True,
     act="swiglu",
     kv_cache_dtype="int8",
-    microbatches=1,
     rules=dict(RULES_ZERO3),
 )

@@ -20,6 +20,5 @@ CONFIG = ModelConfig(
     local_window=2048,
     act="swiglu",
     tie_embeddings=True,
-    microbatches=1,
     rules=dict(RULES_ZERO3),
 )

@@ -17,6 +17,5 @@ CONFIG = ModelConfig(
     vocab=65536,
     block_pattern=("rwkv",),
     act="squared_relu",         # rwkv channel-mix uses relu^2 internally
-    microbatches=1,
     rules=dict(RULES_ZERO3),
 )

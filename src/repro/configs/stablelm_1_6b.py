@@ -12,6 +12,5 @@ CONFIG = ModelConfig(
     d_ff=5632,
     vocab=100352,
     act="swiglu",
-    microbatches=1,
     rules=dict(RULES_ZERO3),
 )

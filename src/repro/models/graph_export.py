@@ -116,7 +116,7 @@ def export_graph(cfg: ModelConfig, cell: ShapeCell, platform: Platform,
         ))
         i += len(span)
         gi += 1
-    head_tokens = cell.global_batch if cell.kind != "train" else tokens
+    head_tokens = cell.global_batch
     costs.append(GroupCosts(
         name="head",
         flops=2.0 * head_tokens * cfg.d_model * cfg.vocab,

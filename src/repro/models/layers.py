@@ -1,12 +1,11 @@
 """Primitive layers: norms, rotary embeddings, attention blocks, MLPs.
 
 Functional style: ``init_*`` builds ``(params, specs)`` where ``specs``
-mirrors the param tree with tuples of *logical* axis names consumed by
-:mod:`repro.models.sharding`.  Forward functions are pure.
+mirrors the param tree with tuples of *logical* axis names, the names
+:mod:`repro.models.sharding` resolves against a mesh.  Forward functions
+are pure.
 """
 from __future__ import annotations
-
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -36,40 +35,11 @@ def dense_init(key, shape, logical, dtype, fan_in_axes=(0,)):
 # ---------------------------------------------------------------------------
 
 
-import functools
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def rmsnorm(x, scale, eps: float = 1e-6):
-    """RMSNorm, f32 math inside, activation-dtype cotangents outside.
-
-    A plain autodiff rmsnorm leaks f32 (B,S,d) cotangents onto the backward
-    spine (via the x->f32 cast), doubling the bytes of every TP all-reduce
-    behind it (observed in the v0 roofline).  The custom VJP computes the
-    backward in f32 but hands back dx in x's dtype.
-    """
-    return _rms_fwd(x, scale, eps)[0]
-
-
-def _rms_fwd(x, scale, eps):
+    """RMSNorm with f32 math inside, in x's dtype outside."""
     xf = x.astype(jnp.float32)
     inv = jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
-    y = (xf * inv * (1.0 + scale.astype(jnp.float32))).astype(x.dtype)
-    return y, (x, scale, inv)
-
-
-def _rms_bwd(eps, res, g):
-    x, scale, inv = res
-    xf = x.astype(jnp.float32)
-    gf = g.astype(jnp.float32)
-    n = xf * inv
-    gn = gf * (1.0 + scale.astype(jnp.float32))
-    dx = inv * (gn - n * jnp.mean(gn * n, -1, keepdims=True))
-    dscale = (gf * n).reshape(-1, x.shape[-1]).sum(0)
-    return dx.astype(x.dtype), dscale.astype(scale.dtype)
-
-
-rmsnorm.defvjp(_rms_fwd, _rms_bwd)
+    return (xf * inv * (1.0 + scale.astype(jnp.float32))).astype(x.dtype)
 
 
 def yarn_freq(d: int, theta: float, yarn):
@@ -135,7 +105,7 @@ def attention_block(cfg: ModelConfig, p, rules, x, positions, *,
                     backend="auto"):
     """Pre-norm attention residual block.
 
-    Train/prefill: ``cache is None`` — self-attention over x; returns
+    Prefill: ``cache is None`` — self-attention over x; returns
     (y, (k, v)) so prefill can build the cache.
     Decode: ``cache = (k_cache, v_cache)``, :mod:`~repro.models.kvcache`
     layer views stored lane-dense as ``(B, S, Hkv·D)`` — or, with
@@ -294,17 +264,3 @@ def logits_head(cfg: ModelConfig, p, rules, x):
                         w.astype(jnp.float32))
     return sharding.constrain(logits, rules, ("batch", "seq", "vocab"))
 
-
-def cross_entropy(cfg: ModelConfig, logits, labels, mask=None):
-    """Mean token NLL + z-loss; logits f32 (B,S,V)."""
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    nll = logz - gold
-    zl = cfg.z_loss * logz ** 2
-    per_tok = nll + zl
-    if mask is None:
-        mask = jnp.ones_like(per_tok)
-    denom = jnp.maximum(mask.sum(), 1.0)
-    loss = (per_tok * mask).sum() / denom
-    return loss, {"nll": (nll * mask).sum() / denom,
-                  "z": (zl * mask).sum() / denom}

@@ -1,4 +1,5 @@
-"""Model facade: init/specs, jit-able step functions, dry-run input specs."""
+"""Model facade: init, the jit-able forward, prefill and decode step, and
+the decode cache."""
 from __future__ import annotations
 
 import functools
@@ -7,7 +8,7 @@ from typing import Mapping
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import SHAPES, ModelConfig, ShapeCell
+from repro.configs.base import ModelConfig
 from . import kvcache, transformer
 
 
@@ -23,97 +24,34 @@ class Model:
         params, _ = transformer.init_stack(self.cfg, key)
         return params
 
-    def _abstract_init(self):
-        box = {}
-
-        def f(k):
-            p, s = transformer.init_stack(self.cfg, k)
-            box["specs"] = s          # static logical tuples, not jax types
-            return p
-
-        params = jax.eval_shape(f, jax.random.PRNGKey(0))
-        return params, box["specs"]
-
-    def specs(self):
-        """Pytree of logical-axis tuples mirroring init()'s params."""
-        return self._abstract_init()[1]
-
     def abstract_params(self):
-        return self._abstract_init()[0]
+        """``init``'s tree as ShapeDtypeStructs, with no allocation."""
+        return jax.eval_shape(self.init, jax.random.PRNGKey(0))
 
     # ------------------------------------------------------------------
-    def loss_fn(self, params, batch):
-        return transformer.loss_fn(self.cfg, params, batch, self.rules,
-                                   backend=self.backend)
-
-    def forward(self, params, batch, last_only=False):
-        logits, _, aux = transformer.forward(
-            self.cfg, params, batch, self.rules, backend=self.backend,
-            last_only=last_only)
-        return logits, aux
+    def forward(self, params, batch):
+        """Returns (logits, stats) over the whole sequence."""
+        logits, _, stats = transformer.forward(
+            self.cfg, params, batch, self.rules, backend=self.backend)
+        return logits, stats
 
     def prefill(self, params, batch, capacity: int | None = None):
         """Returns (last-token logits, decode caches, stats).
 
         ``capacity``: cache slots to allocate (default prompt length + 64
         so a generation loop can append without reallocation).  ``stats``
-        holds the served MoE layers' ``moe_rows`` (layers, held experts)
-        and ``moe_dropped``; it is empty for a model without MoE."""
+        holds the MoE layers' ``moe_rows`` (layers, held experts) and
+        ``moe_dropped``; it is empty for a model without MoE."""
         seq = (batch["embeds"] if self.cfg.embeds_only
                else batch["token_ids"]).shape[1]
-        logits, caches, aux = transformer.forward(
+        return transformer.forward(
             self.cfg, params, batch, self.rules, backend=self.backend,
-            collect_kv=True, last_only=True,
-            cache_capacity=capacity or seq + 64)
-        return logits, caches, {k: aux[k] for k in transformer.STATS
-                                if k in aux}
+            capacity=capacity or seq + 64, last_only=True)
 
     def decode_step(self, params, caches, batch):
         """Returns (logits, new caches, stats), ``stats`` as ``prefill``'s."""
         return transformer.decode_step(self.cfg, params, caches, batch,
                                        self.rules, backend=self.backend)
-
-    # ------------------------------------------------------------------
-    # dry-run stand-ins: ShapeDtypeStructs, no allocation
-    # ------------------------------------------------------------------
-    def input_specs(self, shape: str | ShapeCell):
-        cell = SHAPES[shape] if isinstance(shape, str) else shape
-        cfg = self.cfg
-        B, S = cell.global_batch, cell.seq_len
-        i32 = jnp.int32
-        act = jnp.dtype(cfg.dtype)
-        sds = jax.ShapeDtypeStruct
-
-        def token_inputs(seq):
-            if cfg.embeds_only:     # [audio]/encoder stub: frame embeddings
-                return {"embeds": sds((B, seq, cfg.d_model), act)}
-            d = {"token_ids": sds((B, seq), i32)}
-            if cfg.mm_prefix:       # [vlm] stub: precomputed patch embeds
-                d["mm_embeds"] = sds((B, cfg.mm_prefix, cfg.mm_embed_dim),
-                                     act)
-            return d
-
-        if cell.kind == "train":
-            batch = token_inputs(S)
-            batch["labels"] = sds((B, S), i32)
-            return batch
-        if cell.kind == "prefill":
-            return token_inputs(S)
-        # decode: one new token + caches holding `seq_len` history
-        batch = {"lengths": sds((B,), i32)}
-        if cfg.embeds_only:
-            batch["embeds"] = sds((B, 1, cfg.d_model), act)
-        else:
-            # decode is always past the multimodal prefix: token ids only
-            batch["token_ids"] = sds((B, 1), i32)
-        return batch
-
-    def cache_specs(self, shape: str | ShapeCell):
-        """Abstract decode caches with capacity = cell.seq_len."""
-        cell = SHAPES[shape] if isinstance(shape, str) else shape
-        caches = jax.eval_shape(
-            lambda: self.init_cache(cell.global_batch, cell.seq_len))
-        return caches
 
     def init_cache(self, batch: int, capacity: int):
         cfg = self.cfg

@@ -105,30 +105,6 @@ def named_sharding(mesh: Mesh, rules: Mapping[str, object],
     return NamedSharding(mesh, s)
 
 
-def _is_logical(x):
-    # NB: the empty tuple is a container (e.g. an empty "tail"), not a
-    # scalar spec — scalar params don't occur in the model trees.
-    return isinstance(x, tuple) and len(x) > 0 and all(
-        isinstance(e, (str, type(None))) for e in x)
-
-
-def tree_shardings(mesh: Mesh, rules: Mapping[str, object], spec_tree,
-                   shape_tree=None):
-    """Map a pytree of logical-axis tuples to NamedShardings.
-
-    ``shape_tree``: matching pytree of arrays/ShapeDtypeStructs enabling the
-    divisibility fallback for jit input shardings.
-    """
-    if shape_tree is None:
-        return jax.tree.map(lambda lg: named_sharding(mesh, rules, lg),
-                            spec_tree, is_leaf=_is_logical)
-    flat_specs, treedef = jax.tree.flatten(spec_tree, is_leaf=_is_logical)
-    flat_shapes = treedef.flatten_up_to(shape_tree)
-    out = [named_sharding(mesh, rules, lg, x.shape)
-           for lg, x in zip(flat_specs, flat_shapes)]
-    return treedef.unflatten(out)
-
-
 def weight_use(w, rules: Mapping[str, object],
                logical: Sequence[str | None]):
     """FSDP weight-gather: constrain a *stored-sharded* weight to its
@@ -139,18 +115,9 @@ def weight_use(w, rules: Mapping[str, object],
     THE ACTIVATIONS (e.g. f32[B,S,d] per projection — the dominant
     collective in the v0 baseline roofline).  Constraining the weight to
     embed->None forces the intended all-gather of the (much smaller)
-    weight instead; the gradient flows back through the constraint and is
-    reduce-scattered to the storage sharding by the optimizer update.
+    weight instead.
     """
     rules2 = dict(rules)
     rules2["embed"] = None
     return constrain(w, rules2, logical)
 
-
-def resolved_size(rules: Mapping[str, object], logical: str) -> int:
-    """Product of mesh-axis sizes a logical axis resolves to (1 off-mesh)."""
-    mesh = _current_mesh()
-    if mesh is None:
-        return 1
-    axis = _resolve_axis(rules.get(logical), tuple(mesh.axis_names))
-    return _axis_size(mesh, axis)

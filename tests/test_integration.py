@@ -101,36 +101,3 @@ class TestShardingRules:
             s = sharding.spec(rules, (name,), self.make_mesh())
             assert s == P()
 
-
-class TestRooflineAnalysis:
-    def test_collective_parse(self):
-        from repro.analysis import roofline
-        hlo = """
-  %all-reduce.2 = f32[16,1024]{1,0} all-reduce(%dot), channel_id=1, replica_groups=[16,16]<=[256], use_global_device_ids=true
-  %all-gather.3 = bf16[32,2048]{1,0} all-gather(%p), replica_groups=[16,16]<=[256], dimensions={1}
-  %x = f32[8,8]{1,0} add(%a, %b)
-"""
-        st = roofline.parse_collectives(hlo)
-        assert st.op_counts == {"all-reduce": 1, "all-gather": 1}
-        ar = 16 * 1024 * 4
-        ag = 32 * 2048 * 2 / 16
-        assert st.operand_bytes == pytest.approx(ar + ag)
-
-    def test_analytic_bytes_monotone_in_depth(self):
-        from repro.analysis import roofline
-        import dataclasses
-        cfg = configs.get("llama3.2-3b")
-        cell = SHAPES["train_4k"]
-        b1 = roofline.analytic_hbm_bytes(cfg, cell)
-        b2 = roofline.analytic_hbm_bytes(
-            dataclasses.replace(cfg, n_layers=cfg.n_layers * 2), cell)
-        assert b2 > b1
-
-    def test_decode_bytes_dominated_by_weights_and_cache(self):
-        from repro.analysis import roofline
-        cfg = configs.get("llama3.2-3b")
-        cell = SHAPES["decode_32k"]
-        total = roofline.analytic_hbm_bytes(cfg, cell)
-        weights = cfg.n_params() * 4
-        assert total > weights                    # cache adds on top
-        assert total < weights * 40               # but stays decode-like

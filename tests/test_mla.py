@@ -137,20 +137,6 @@ def test_logits_do_not_depend_on_batch_neighbours(served):
     assert c.moe_expert_steps == 8 * (3 + c.steps)
 
 
-def test_capacity_dispatch_drops_where_served_does_not():
-    """The training path's capacity drops rows at a decode-sized batch;
-    the served path computes every held assignment."""
-    cfg = dataclasses.replace(tiny_cfg(0, 16), moe=dataclasses.replace(
-        tiny_cfg(0, 16).moe, capacity_factor=0.25))
-    p, _ = moe.init_moe(cfg, jax.random.PRNGKey(0))
-    x = jax.random.normal(jax.random.PRNGKey(1), (12, 1, cfg.d_model))
-    y_cap, _ = moe.moe_block(cfg, p, {}, x)
-    y_srv, st = moe.moe_block_served(cfg, p, {}, x, backend="xla")
-    assert int(st["moe_dropped"]) == 0
-    assert int(st["moe_rows"].sum()) == 12 * cfg.moe.top_k
-    assert float(jnp.abs(y_cap - y_srv).max()) > 1e-3
-
-
 def test_absorbed_decode_matches_the_expanded_form(served):
     """One layer's decode over the latent cache (absorbed: q W_uk^T against
     the cached latents, W_uv after the weighted sum) equals the prefill's
@@ -226,7 +212,7 @@ def test_expert_shares_sum_to_the_uncut_layer(layer_index):
         part = ref.routed(h, w["c"], s, (4 * share, 4))
         total = total + part
         cfg = tiny_cfg(4 * share, 4)
-        y, _ = moe.moe_block_served(cfg, w["c"], {}, x[None], backend="xla")
+        y, _ = moe.moe_block(cfg, w["c"], {}, x[None], backend="xla")
         np.testing.assert_allclose(
             np.asarray(y[0] - x), np.asarray(
                 part + ref._swiglu(h, w["c"]["shared"], None)),

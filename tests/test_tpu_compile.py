@@ -196,21 +196,30 @@ def _assert_cache_updated_in_place(hlo, n_leaves, slots, capacity):
     assert _whole_layer_moves(hlo, slots, capacity) == []
 
 
+def _served_config(arch):
+    """``arch`` at published widths, 3 layers and vocab 1,024, holding
+    8 experts where it has MoE: the engine programs compiled below."""
+    import dataclasses
+
+    from repro import configs
+    base = configs.get(arch)
+    cfg = dataclasses.replace(base, n_layers=3, vocab=1024)
+    if base.moe is not None:
+        cfg = dataclasses.replace(cfg, moe=dataclasses.replace(base.moe,
+                                                               n_held=8))
+    return cfg
+
+
 def test_decode_step_keeps_the_cache_layout(one_chip):
     """stablelm-1.6b at published widths (3 layers, so the scan runs
     three groups; 2 slots of 512): the compiled decode step, its cache
     donated as the engine donates it, writes each new row into the
     stored stacks in place and the kernel reads its layer there, with no
     whole-layer ``copy``, slice or write-back."""
-    import dataclasses
-
-    from repro import configs
     from repro.models import build
 
     slots, capacity = 2, 512
-    cfg = dataclasses.replace(configs.get("stablelm-1.6b"), n_layers=3,
-                              vocab=1024)
-    model = build(cfg, backend="pallas")
+    model = build(_served_config("stablelm-1.6b"), backend="pallas")
     hlo, n_leaves = _decode_step_hlo(one_chip, model, slots, capacity)
     names = re.findall(r"%(\w+)\.\d+ = \S+ custom-call\(", hlo)
     assert "decode_attention" in names
@@ -223,17 +232,38 @@ def test_mla_decode_step_compiles_with_its_kernels(one_chip):
     1,024, 2 slots of 384): the compiled decode step, its cache donated,
     runs the latent-attention and expert kernels under their names and
     updates every latent cache, the leading layer's too, in place."""
-    import dataclasses
-
-    from repro import configs
     from repro.models import build
 
     slots, capacity = 2, 384
-    base = configs.get("deepseek-v2-lite")
-    cfg = dataclasses.replace(base, n_layers=3, vocab=1024, moe=dataclasses
-                              .replace(base.moe, n_held=8))
-    model = build(cfg, backend="pallas")
+    model = build(_served_config("deepseek-v2-lite"), backend="pallas")
     hlo, n_leaves = _decode_step_hlo(one_chip, model, slots, capacity)
     names = re.findall(r"%(\w+)\.\d+ = \S+ custom-call\(", hlo)
     assert "mla_decode" in names and "moe_gmm" in names
     _assert_cache_updated_in_place(hlo, n_leaves, slots, capacity)
+
+
+@pytest.mark.parametrize("arch,kernels", [
+    ("stablelm-1.6b", {"flash_attention"}),
+    ("deepseek-v2-lite", {"flash_attention", "moe_gmm"})],
+    ids=["stablelm-1.6b", "deepseek-v2-lite"])
+def test_prefill_compiles_with_its_kernels(one_chip, arch, kernels):
+    """The prefill as ``ServingEngine`` jits it (one prompt of 256 into a
+    cache of 384) compiles for a v5e with its kernels under their names,
+    and its caches come out in the engine's cache layout: the shapes and
+    dtypes of ``init_cache(1, 384)``."""
+    from repro.models import build
+
+    capacity = 384
+    model = build(_served_config(arch), backend="pallas")
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), model.abstract_params())
+    batch = {"token_ids": jax.ShapeDtypeStruct((1, 256), jnp.int32,
+                                               sharding=one_chip)}
+    prefill = jax.jit(lambda p, b: model.prefill(p, b, capacity=capacity))
+    hlo = prefill.lower(params, batch).compile().as_text()
+    names = set(re.findall(r"%(\w+)\.\d+ = \S+ custom-call\(", hlo))
+    assert kernels <= names
+    layout = lambda t: jax.tree.map(lambda a: (a.shape, a.dtype), t)
+    _, caches, _ = jax.eval_shape(prefill, params, batch)
+    assert layout(caches) == layout(jax.eval_shape(
+        lambda: model.init_cache(1, capacity)))
